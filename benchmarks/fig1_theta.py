@@ -28,4 +28,6 @@ def run(fast: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

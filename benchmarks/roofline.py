@@ -1,11 +1,13 @@
 """Roofline analysis from the dry-run artifacts (deliverable g).
 
 Reads experiments/dryrun/*.json (produced by repro.launch.dryrun), computes
-the three per-chip roofline terms on the TPU v5e target
+the three per-chip roofline terms on the chip the production mesh targets
+(``repro.launch.mesh.PRODUCTION_DEVICE_KIND``, a TPU v5e; peaks from
+``mesh.chip_peaks``)
 
-    compute    = HLO_FLOPs_per_device / 197e12
-    memory     = HLO_bytes_per_device / 819e9
-    collective = collective_bytes_per_device / 50e9
+    compute    = HLO_FLOPs_per_device / peak bf16 FLOP/s      (197e12)
+    memory     = HLO_bytes_per_device / peak HBM bytes/s      (819e9)
+    collective = collective_bytes_per_device / ICI link bytes/s (50e9)
 
 identifies the dominant term, and reports MODEL_FLOPS / HLO_FLOPs (useful-
 compute ratio; catches remat/redundancy waste). MODEL_FLOPS uses 6*N*D for
@@ -24,7 +26,7 @@ import os
 import jax
 
 from repro.configs.base import SHAPES, get_config
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, chip_peaks
 
 
 def active_params(arch: str) -> tuple[int, int]:
@@ -61,6 +63,7 @@ def model_flops(arch: str, shape_name: str) -> float:
 
 
 def analyze(report: dict) -> dict:
+    peaks = chip_peaks(PRODUCTION_DEVICE_KIND)
     arch, shape = report["arch"], report["shape"]
     chips = report["chips"]
     hlo = report.get("hlo")
@@ -72,14 +75,15 @@ def analyze(report: dict) -> dict:
         flops_dev = report["flops_per_device"]
         bytes_dev = report["bytes_per_device"]
         coll_dev = report["collectives"]["total"]
-    t_compute = flops_dev / PEAK_FLOPS_BF16
-    t_memory = bytes_dev / HBM_BW
-    t_coll = coll_dev / ICI_BW
+    t_compute = flops_dev / peaks["flops_bf16"]
+    t_memory = bytes_dev / peaks["hbm_bytes_per_s"]
+    t_coll = coll_dev / peaks["ici_link_bytes_per_s"]
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_coll}
     dominant = max(terms, key=terms.get)
     mf = model_flops(arch, shape) / chips
     return {
         "arch": arch, "shape": shape, "mesh": report["mesh"],
+        "device_kind": PRODUCTION_DEVICE_KIND,
         "compute_s": t_compute, "memory_s": t_memory, "collective_s": t_coll,
         "dominant": dominant,
         "model_flops_per_dev": mf,

@@ -19,9 +19,12 @@ a >20% rounds/sec regression (override with ``--tolerance`` or
 committed bars scale with the measured loop drift, so a uniformly slower
 runner passes while an engine that lost its dispatch amortization fails.
 
-Usage:
-  PYTHONPATH=src:. python benchmarks/round_bench.py            # full + write
-  PYTHONPATH=src:. python benchmarks/round_bench.py --smoke --check  # CI gate
+Usage (the plan-gossip rows need a mesh; on the CPU give the process
+virtual devices, which ``make bench`` / ``make bench-smoke`` do):
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+    PYTHONPATH=src:. python benchmarks/round_bench.py            # full + write
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+    PYTHONPATH=src:. python benchmarks/round_bench.py --smoke --check  # CI gate
 """
 from __future__ import annotations
 
@@ -29,9 +32,7 @@ import argparse
 import json
 import os
 import pathlib
-import subprocess
 import sys
-import textwrap
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +45,7 @@ from repro.core.cola import ColaConfig, build_env, run_cola
 from repro.core.partition import make_partition
 from repro.data import synthetic
 from repro.dist.runtime import run_dist_cola
+from repro.launch.compile_cache import enable_compile_cache
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 BENCH_PATH = ROOT / "BENCH_cola.json"
@@ -63,9 +65,9 @@ _REC_KINDS = ("gap", "cert")
 _REC_EVERY = ("1", "10", "inf")
 _REC_KEYS = tuple(f"rec_{m}_{r}_e{e}_rounds_per_sec"
                   for m in _REC_MODES for r in _REC_KINDS for e in _REC_EVERY)
-# plan-executed gossip vs the dense all-gather on a REAL 8-device node mesh
-# (subprocess: plan execution places one node per device) — the rows that
-# gate the topology-program compiler's dispatch overhead
+# plan-executed gossip vs the dense all-gather on a node mesh over this
+# process's devices — the rows that gate the topology-program compiler's
+# dispatch overhead
 _PLAN_KEYS = ("plan_gossip_rounds_per_sec", "dense_gossip_rounds_per_sec")
 _GATED = ("block_rounds_per_sec", "dist_block_rounds_per_sec") \
     + _REC_KEYS + _PLAN_KEYS
@@ -191,29 +193,34 @@ def bench_sampled(smoke: bool = False) -> dict:
             _SAMPLED_KEY: round(sampled_rps, 2)}
 
 
-_PLAN_BENCH_SCRIPT = textwrap.dedent("""
-    import os, sys
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    import json
-    import jax, jax.numpy as jnp, numpy as np
-    from benchmarks.common import timeit_rounds
-    from repro.core import problems, topology as topo
-    from repro.core.cola import ColaConfig
-    from repro.data import synthetic
-    from repro.dist.runtime import run_dist_cola
+def bench_plan_gossip(smoke: bool = False) -> dict:
+    """Plan-executed gossip vs the dense all-gather on a K=8 torus 2x4
+    (non-circulant, so only the plan path keeps neighbor-only comm), with
+    the node axis over this process's devices: one node per device on 8, a
+    BlockPlan on 2 or 4. A process with one device has no mesh to measure,
+    so this raises; on the CPU give the process virtual devices
+    (``XLA_FLAGS=--xla_force_host_platform_device_count=8``)."""
+    from repro.analysis import drivers as an_drivers
 
-    rounds, n_s, n_f = (int(a) for a in sys.argv[1:4])
+    k = 8
+    n_dev = jax.device_count()
+    if n_dev < 2:
+        raise RuntimeError(
+            f"plan gossip bench needs a mesh of 2, 4 or 8 devices; this "
+            f"process has {n_dev} ({jax.devices()[0].platform}). On the CPU "
+            "set XLA_FLAGS=--xla_force_host_platform_device_count=8")
+    m = max(c for c in (2, 4, 8) if c <= n_dev)
+    rounds = 50 if smoke else 200
+    n_s, n_f = (128, 64) if smoke else (256, 128)
     x, y, _ = synthetic.regression(n_s, n_f, seed=0)
     prob = problems.ridge_primal(jnp.asarray(x), jnp.asarray(y), 1e-2)
-    graph = topo.torus_2d(2, 4)  # non-circulant: the plan path's home turf
+    graph = topo.torus_2d(2, 4)
     cfg = ColaConfig(kappa=1.0)
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((m,), ("data",), devices=jax.devices()[:m])
 
-    def bench(comm, run_cfg=cfg):
-        return timeit_rounds(
-            lambda: run_dist_cola(prob, graph, run_cfg, mesh, rounds,
-                                  comm=comm, record_every=rounds - 1),
-            rounds, label="plan_" + comm)
+    def run(c, comm="plan"):
+        return run_dist_cola(prob, graph, c, mesh, rounds, comm=comm,
+                             record_every=rounds - 1)
 
     # the robust/plan and pipe/quant gates are RATIOS of two same-run
     # measurements, so time each pair INTERLEAVED (timeit_rounds with two
@@ -222,8 +229,6 @@ _PLAN_BENCH_SCRIPT = textwrap.dedent("""
     # rounds a single rep is ~30ms and best-of-3 back-to-back still carries
     # +-20% jitter
     def bench_pair(cfg_a, cfg_b, reps=8):
-        run = lambda c: run_dist_cola(prob, graph, c, mesh, rounds,
-                                      comm="plan", record_every=rounds - 1)
         bests, results = timeit_rounds(
             [lambda: run(cfg_a), lambda: run(cfg_b)], rounds, repeats=reps,
             label="plan_pair")
@@ -231,62 +236,41 @@ _PLAN_BENCH_SCRIPT = textwrap.dedent("""
 
     plan_rps, plan_res, robust_rps, robust_res = bench_pair(
         cfg, ColaConfig(kappa=1.0, robust="trim"))
-    dense_rps, dense_res = bench("dense")
+    dense_rps, dense_res = timeit_rounds(lambda: run(cfg, "dense"), rounds,
+                                         label="plan_dense")
     assert np.allclose(plan_res.history["primal"][-1],
-                       dense_res.history["primal"][-1], rtol=1e-5), \\
+                       dense_res.history["primal"][-1], rtol=1e-5), \
         "plan gossip diverged from the dense oracle"
     assert np.allclose(robust_res.history["primal"][-1],
-                       plan_res.history["primal"][-1], rtol=1e-5), \\
+                       plan_res.history["primal"][-1], rtol=1e-5), \
         "robust trim on a clean run diverged from plain plan gossip"
 
     quant_rps, quant_res, pipe_rps, pipe_res = bench_pair(
         ColaConfig(kappa=1.0, wire="int8"),
         ColaConfig(kappa=1.0, wire="int8", pipeline=True))
     assert np.array_equal(np.asarray(quant_res.state.x_parts),
-                          np.asarray(pipe_res.state.x_parts)), \\
+                          np.asarray(pipe_res.state.x_parts)), \
         "pipelined int8 run diverged from the unpipelined one"
 
     # HLO structure: the pipelined body's first ppermute must consume the
     # CARRIED double buffer (operand chain free of compute) — and the
     # unpipelined body must fail the same check, or the checker is vacuous
-    from repro.analysis import drivers as an_drivers
-    hlo_p, _ = an_drivers.quant_round_hlo(prob, graph, 8, 4, "int8",
+    hlo_p, _ = an_drivers.quant_round_hlo(prob, graph, k, m, "int8",
                                           pipeline=True)
-    hlo_u, _ = an_drivers.quant_round_hlo(prob, graph, 8, 4, "int8")
+    hlo_u, _ = an_drivers.quant_round_hlo(prob, graph, k, m, "int8")
     order_ok = (not an_drivers.pipeline_order_findings(hlo_p, "bench")
                 and bool(an_drivers.pipeline_order_findings(hlo_u, "bench")))
 
-    print("PLANBENCH " + json.dumps(
-        {"plan_gossip_rounds_per_sec": round(plan_rps, 2),
-         "dense_gossip_rounds_per_sec": round(dense_rps, 2),
-         "robust_gossip_rounds_per_sec": round(robust_rps, 2),
-         "quant_gossip_rounds_per_sec": round(quant_rps, 2),
-         "pipelined_gossip_rounds_per_sec": round(pipe_rps, 2),
-         "pipeline_order_ok": order_ok}))
-""")
-
-
-def bench_plan_gossip(smoke: bool = False) -> dict:
-    """Plan-executed gossip vs dense all-gather on an 8-virtual-device node
-    mesh (torus 2x4 — non-circulant, so only the plan path keeps
-    neighbor-only comm). Runs in a subprocess so the main process keeps the
-    single real CPU device for the other rows."""
-    rounds = 50 if smoke else 200
-    n_s, n_f = (128, 64) if smoke else (256, 128)
-    env = dict(os.environ, PYTHONPATH="src:.")
-    out = subprocess.run(
-        [sys.executable, "-c", _PLAN_BENCH_SCRIPT, str(rounds), str(n_s),
-         str(n_f)], env=env, capture_output=True, text=True, timeout=900,
-        cwd=str(ROOT))
-    for line in out.stdout.splitlines():
-        if line.startswith("PLANBENCH "):
-            vals = json.loads(line[len("PLANBENCH "):])
-            for key, rps in vals.items():
-                csv_row("round_bench", key, f"K=8,T={rounds}",
-                        str(rps) if isinstance(rps, bool) else f"{rps:.1f}")
-            return vals
-    raise RuntimeError("plan gossip bench subprocess failed:\n"
-                       + out.stdout + "\n" + out.stderr)
+    vals = {"plan_gossip_rounds_per_sec": round(plan_rps, 2),
+            "dense_gossip_rounds_per_sec": round(dense_rps, 2),
+            "robust_gossip_rounds_per_sec": round(robust_rps, 2),
+            "quant_gossip_rounds_per_sec": round(quant_rps, 2),
+            "pipelined_gossip_rounds_per_sec": round(pipe_rps, 2),
+            "pipeline_order_ok": order_ok}
+    for key, rps in vals.items():
+        csv_row("round_bench", key, f"K={k},M={m},T={rounds}",
+                str(rps) if isinstance(rps, bool) else f"{rps:.1f}")
+    return vals
 
 
 def bench_recording(smoke: bool = False) -> dict:
@@ -539,4 +523,5 @@ if __name__ == "__main__":
     ap.add_argument("--tolerance", type=float,
                     default=float(os.environ.get("BENCH_TOLERANCE", "0.2")))
     args = ap.parse_args()
+    enable_compile_cache()
     run(smoke=args.smoke, check=args.check, tolerance=args.tolerance)

@@ -79,7 +79,7 @@ def plan_round_hlo(prob, graph, k: int, *, inject_all_gather: bool = False):
     from jax import lax
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro import topo as rtopo
-    from repro.core import mixing, topology as topo
+    from repro.core import topology as topo
     from repro.core.cola import ColaConfig, _round_body, build_env, \
         init_state
     from repro.core.partition import make_partition
@@ -109,8 +109,8 @@ def plan_round_hlo(prob, graph, k: int, *, inject_all_gather: bool = False):
         return new
 
     state_spec, env_spec = cola_state_pspecs("data"), cola_env_pspecs("data")
-    shard_step = mixing.shard_map(
-        round_fn, mesh,
+    shard_step = jax.shard_map(
+        round_fn, mesh=mesh,
         in_specs=(state_spec, env_spec, plan_payload_pspecs("data"),
                   P("data")),
         out_specs=state_spec)
@@ -136,7 +136,7 @@ def dense_round_hlo(prob, graph, k: int) -> str:
     program that MUST move the (K, d) stack."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.core import mixing, topology as topo
+    from repro.core import topology as topo
     from repro.core.cola import ColaConfig, _round_body, build_env, \
         init_state
     from repro.core.partition import make_partition
@@ -151,8 +151,8 @@ def dense_round_hlo(prob, graph, k: int) -> str:
     mix_d, grad_d = rt._dist_mixers("data", 1, 1, "dense", cfg.gossip_steps)
     body_d = _round_body(prob, part, cfg, mix_fn=mix_d, grad_mix_fn=grad_d)
     state_spec, env_spec = cola_state_pspecs("data"), cola_env_pspecs("data")
-    shard_d = mixing.shard_map(
-        lambda st, e, w_, act: body_d(st, e, w_, act), mesh,
+    shard_d = jax.shard_map(
+        lambda st, e, w_, act: body_d(st, e, w_, act), mesh=mesh,
         in_specs=(state_spec, env_spec, P(), P("data")),
         out_specs=state_spec)
     w = topo.metropolis_weights(graph)
@@ -169,13 +169,21 @@ def dense_round_hlo(prob, graph, k: int) -> str:
 
 
 def block_round_hlo(prob, graph, k: int, m: int, *,
-                    robust: str | None = None):
+                    robust: str | None = None, mesh=None):
     """Compiled HLO of the block-mode round (K nodes on M < K devices)
-    plus its ``BlockPlan``."""
+    plus its ``BlockPlan``.
+
+    ``mesh``: an M-device mesh with a ``data`` axis to compile for
+    (default: one over this process's devices); a mesh of described TPU
+    devices compiles without a chip. The round is built from shapes, so
+    ``prob.a`` may be a ``jax.ShapeDtypeStruct``.
+    """
+    import dataclasses
+
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro import topo as rtopo
-    from repro.core import mixing, topology as topo
+    from repro.core import topology as topo
     from repro.core.cola import ColaConfig, _round_body, build_env, \
         init_state
     from repro.core.partition import make_partition
@@ -183,10 +191,12 @@ def block_round_hlo(prob, graph, k: int, m: int, *,
     from repro.dist.sharding import (block_payload_pspec, cola_env_pspecs,
                                      cola_state_pspecs)
 
-    _require_devices(m)
+    if mesh is None:
+        _require_devices(m)
+        mesh = jax.make_mesh((m,), ("data",))
     part = make_partition(prob.n, k)
-    env = build_env(prob, part)
-    mesh = jax.make_mesh((m,), ("data",))
+    env = jax.eval_shape(
+        lambda a: build_env(dataclasses.replace(prob, a=a), part), prob.a)
     plan = rtopo.compile_block_plan(graph, m)
     cfg = ColaConfig(kappa=1.0, robust=robust)
     mix_fn, grad_mix_fn = rt._dist_mixers(
@@ -194,15 +204,15 @@ def block_round_hlo(prob, graph, k: int, m: int, *,
     body = _round_body(prob, part, cfg, mix_fn=mix_fn,
                       grad_mix_fn=grad_mix_fn)
     state_spec, env_spec = cola_state_pspecs("data"), cola_env_pspecs("data")
-    shard_step = mixing.shard_map(
-        lambda st, e, pay, act: body(st, e, pay, act), mesh,
+    shard_step = jax.shard_map(
+        lambda st, e, pay, act: body(st, e, pay, act), mesh=mesh,
         in_specs=(state_spec, env_spec, block_payload_pspec("data"),
                   P("data")),
         out_specs=state_spec)
     w = topo.metropolis_weights(graph).astype(np.float32)
-    sds = lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype)
-    args = (jax.tree.map(sds, init_state(prob, part)),
-            jax.tree.map(sds, env), sds(w), sds(np.ones(k, np.float32)))
+    sds = lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype)
+    args = (jax.eval_shape(lambda: init_state(prob, part)), env, sds(w),
+            sds(np.ones(k, np.float32)))
     sh = lambda spec: NamedSharding(mesh, spec)
     in_sh = (jax.tree.map(lambda _: sh(state_spec), args[0]),
              jax.tree.map(lambda _: sh(env_spec), args[1]),
@@ -232,7 +242,7 @@ def quant_round_hlo(prob, graph, k: int, m: int, wire: str, *,
     from jax import lax
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro import topo as rtopo
-    from repro.core import mixing, quant, topology as topo
+    from repro.core import quant, topology as topo
     from repro.core.cola import (ColaConfig, _arm_wire_state, _round_body,
                                  build_env, init_state)
     from repro.core.partition import make_partition
@@ -270,8 +280,8 @@ def quant_round_hlo(prob, graph, k: int, m: int, wire: str, *,
     keys = np.asarray(quant.round_keys(0, 2))
     state = _arm_wire_state(state, cfg, keys[0])
     state_spec, env_spec = cola_state_pspecs("data"), cola_env_pspecs("data")
-    shard_step = mixing.shard_map(
-        round_fn, mesh,
+    shard_step = jax.shard_map(
+        round_fn, mesh=mesh,
         in_specs=(state_spec, env_spec, block_payload_pspec("data"),
                   P("data"), P(), P()),
         out_specs=state_spec)
@@ -430,6 +440,54 @@ def _sim_block_program(cfg):
     return block, (state, env, sched)
 
 
+def sim_block_compiled(prob, graph, cfg, block_size: int, *, device):
+    """Compile, from shapes, the block program ``run_cola`` dispatches on a
+    static graph with the gap recorder: ``executor.block_program`` over
+    ``block_size`` rounds, the state donated, the env and the recorder's
+    arrays (the data matrix first) as arguments. ``device``: where to
+    compile; a described TPU device compiles without a chip. ``prob.a``
+    may be a ``jax.ShapeDtypeStruct``.
+    """
+    import dataclasses
+
+    import jax
+    from jax.sharding import SingleDeviceSharding
+    from repro.core import executor, metrics as metrics_lib
+    from repro.core.cola import _round_body, build_env, init_state
+    from repro.core.partition import make_partition
+
+    k = graph.num_nodes
+    part = make_partition(prob.n, k)
+    body = _round_body(prob, part, cfg)
+
+    def step_fn(s, env, sched_t):
+        return body(s, env, sched_t["w"], sched_t["active"]), None
+
+    def on_device(tree):
+        sharding = SingleDeviceSharding(device)
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding), tree)
+
+    with_a = lambda f: lambda a: f(dataclasses.replace(prob, a=a))
+    a_sds = jax.ShapeDtypeStruct(prob.a.shape, prob.a.dtype)
+    env = jax.eval_shape(with_a(lambda p: build_env(
+        p, part, with_gram=cfg.use_gram(p.d, part.block,
+                                        p.a.dtype.itemsize))), a_sds)
+    state = jax.eval_shape(with_a(lambda p: init_state(p, part)), a_sds)
+    sched = {"w": jax.ShapeDtypeStruct((block_size, k, k), np.float32),
+             "active": jax.ShapeDtypeStruct((block_size, k), np.float32)}
+    sched_t = {n: jax.ShapeDtypeStruct(x.shape[1:], x.dtype)
+               for n, x in sched.items()}
+    lifted_rec, rec_consts = executor.lift_constants(
+        lambda a, s, _t: metrics_lib.GapRecorder(
+            dataclasses.replace(prob, a=a), part).record_fn(s),
+        state, sched_t, data=(a_sds,))
+    program = executor.block_program(step_fn, lifted_rec)
+    args = on_device((state, (env, rec_consts), sched,
+                      jax.ShapeDtypeStruct((block_size,), np.bool_)))
+    return program.lower(*args).compile()
+
+
 def _check_sim(cfg, name: str) -> List[Finding]:
     block, args = _sim_block_program(cfg)
     findings = passes.run_jaxpr_passes(block, *args, where=name)
@@ -489,7 +547,7 @@ def check_gossip_dp() -> List[Finding]:
 def check_dist_ring() -> List[Finding]:
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.core import mixing, topology as topo
+    from repro.core import topology as topo
     from repro.core.cola import ColaConfig, _round_body, build_env, \
         init_state
     from repro.core.partition import make_partition
@@ -508,8 +566,8 @@ def check_dist_ring() -> List[Finding]:
     body = _round_body(prob, part, cfg, mix_fn=mix_fn,
                       grad_mix_fn=grad_mix_fn)
     state_spec, env_spec = cola_state_pspecs("data"), cola_env_pspecs("data")
-    shard_step = mixing.shard_map(
-        lambda st, e, w_, act: body(st, e, w_, act), mesh,
+    shard_step = jax.shard_map(
+        lambda st, e, w_, act: body(st, e, w_, act), mesh=mesh,
         in_specs=(state_spec, env_spec, P(), P("data")),
         out_specs=state_spec)
     w = topo.metropolis_weights(topo.ring(k))

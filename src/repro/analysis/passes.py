@@ -36,7 +36,7 @@ from typing import Callable, Iterator, List
 
 import jax
 import numpy as np
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.core import executor
 

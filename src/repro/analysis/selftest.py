@@ -37,9 +37,8 @@ def seeded_dtype_drift() -> List[Finding]:
     """A silent f32 -> f64 -> f32 round-trip inside the program."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
-    with enable_x64():
+    with jax.enable_x64(True):
         def fn(x):
             acc = x.astype(jnp.float64) * 2.0
             return acc.astype(jnp.float32)

@@ -43,6 +43,7 @@ from repro.core import executor as exec_engine, metrics as metrics_lib, \
     mixing, quant, schedule as schedule_lib, topology as topo
 from repro.core.duality import GapReport, gap_report
 from repro.core.partition import Partition, make_partition
+from repro.core.precision import MATMUL
 from repro.core.problems import Problem
 from repro.core.subproblem import (SubproblemSpec, block_gram, cd_solve_all,
                                    gram_pays)
@@ -315,7 +316,7 @@ def _round_body(problem: Problem, part: Partition, cfg: ColaConfig, *,
 
         # Steps 6-8: local variable + local estimate updates.
         x_new = state.x_parts + cfg.gamma * dx
-        dv = jnp.einsum("kdn,kn->kd", env.a_parts, dx)
+        dv = jnp.einsum("kdn,kn->kd", env.a_parts, dx, precision=MATMUL)
         v_new = v_half + cfg.gamma * k * dv
         if not quantized:
             return ColaState(x_parts=x_new, v_stack=v_new)
@@ -946,7 +947,7 @@ def _run_cola_cohort(problem, graph, cfg, rounds, *, part, record_every,
                           gram_parts=env_ctx.gram_parts[idx] if use_gram
                           else None)
         # Steps 6-8 scattered back: frozen nodes keep x and v verbatim
-        dv = jnp.einsum("kdn,kn->kd", a_sub, dx)
+        dv = jnp.einsum("kdn,kn->kd", a_sub, dx, precision=MATMUL)
         x_new = st.x_parts.at[idx].add(gamma * dx)
         v_new = st.v_stack.at[idx].set(v_half + gamma * k * dv)
         return ColaState(x_parts=x_new, v_stack=v_new), None
@@ -984,7 +985,8 @@ def _reset_leavers(state: ColaState, env: ColaEnv, part: Partition,
     """
     leave = jnp.asarray(leavers)
     contrib = jnp.einsum("kdn,kn->kd", env.a_parts,
-                         state.x_parts * leave[:, None])  # (K, d)
+                         state.x_parts * leave[:, None],
+                         precision=MATMUL)                # (K, d)
     if total_fn is None:
         total_fn = lambda c: jnp.sum(c, axis=0)           # A_[k] x_[k] summed
     total = total_fn(contrib)

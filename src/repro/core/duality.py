@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.partition import Partition
+from repro.core.precision import MATMUL
 
 
 class GapReport(NamedTuple):
@@ -50,13 +51,14 @@ def gap_report(problem, part: Partition, x_parts: jax.Array,
                v_stack: jax.Array) -> GapReport:
     """All Lemma-1/2 quantities at the optimality choice w_k = grad f(v_k)."""
     x = part.merge_vector(x_parts)
-    ax = problem.a @ x
+    ax = jnp.matmul(problem.a, x, precision=MATMUL)
     w_stack = jax.vmap(problem.grad_f)(v_stack)          # (K, d)
     w_bar = jnp.mean(w_stack, axis=0)
     f_vals = jax.vmap(problem.f)(v_stack)
     fc_vals = jax.vmap(problem.f_conj)(w_stack)
     g_val = problem.g(x)
-    gc_val = jnp.sum(problem.g_conj_el(-(problem.a.T @ w_bar), problem.g_params()))
+    atw = jnp.matmul(problem.a.T, w_bar, precision=MATMUL)
+    gc_val = jnp.sum(problem.g_conj_el(-atw, problem.g_params()))
     h_a = jnp.mean(f_vals) + g_val
     h_b = jnp.mean(fc_vals) + gc_val
     gap = h_a + h_b
@@ -85,14 +87,16 @@ def block_spectral_norms(a_parts: jax.Array, iters: int = 50,
     v0 = jax.random.normal(key, (k, n_k), dtype=a_parts.dtype)
 
     def body(_, v):
-        u = jnp.einsum("kdn,kn->kd", a_parts, v)
-        w = jnp.einsum("kdn,kd->kn", a_parts, u)
+        u = jnp.einsum("kdn,kn->kd", a_parts, v, precision=MATMUL)
+        w = jnp.einsum("kdn,kd->kn", a_parts, u, precision=MATMUL)
         return w / (jnp.linalg.norm(w, axis=1, keepdims=True) + 1e-30)
 
     v = jax.lax.fori_loop(0, iters, body, v0)
-    u = jnp.einsum("kdn,kn->kd", a_parts, v)
-    num = jnp.einsum("kn,kn->k", jnp.einsum("kdn,kd->kn", a_parts, u), v)
-    den = jnp.einsum("kn,kn->k", v, v)
+    u = jnp.einsum("kdn,kn->kd", a_parts, v, precision=MATMUL)
+    num = jnp.einsum("kn,kn->k",
+                     jnp.einsum("kdn,kd->kn", a_parts, u, precision=MATMUL),
+                     v, precision=MATMUL)
+    den = jnp.einsum("kn,kn->k", v, v, precision=MATMUL)
     return num / (den + 1e-30)
 
 
@@ -161,9 +165,11 @@ def node_subproblem_gaps(problem, x_parts: jax.Array, v_stack: jax.Array,
     """(K,) LHS of condition (9): each node's local subproblem duality gap,
     from node-local quantities only (no cross-node data at all)."""
     def node_gap(v_k, g_k, a_k, x_k, gp_k, m_k):
-        conj = problem.g_conj_el(-(a_k.T @ g_k), gp_k)
+        conj = problem.g_conj_el(
+            -jnp.matmul(a_k.T, g_k, precision=MATMUL), gp_k)
         prim = problem.g_el(x_k, gp_k)
-        return jnp.dot(v_k, g_k) + jnp.sum((prim + conj) * m_k)
+        return (jnp.dot(v_k, g_k, precision=MATMUL)
+                + jnp.sum((prim + conj) * m_k))
 
     return jax.vmap(node_gap)(v_stack, grads, a_parts, x_parts,
                               gp_parts, masks)

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.extend import core as jcore
 
 # Compiled-driver cache: jit only caches on the *function object*, and every
 # run_cola/run_round_blocks call builds fresh closures, so without this each
@@ -271,7 +272,7 @@ def cache_listener(fn: Callable[[Any, str], None]):
             pass
 
 
-def cached_driver(key, build: Callable[[], Callable]) -> Callable:
+def cached_driver(key, build: Callable[[], Any]) -> Any:
     """Return (building on miss) the jitted driver for ``key``.
 
     ``key`` must uniquely determine the semantics AND closure constants of
@@ -316,6 +317,191 @@ def _num_rounds(schedule: Any, record_mask: np.ndarray | None,
         raise ValueError("cannot infer the round count: pass num_rounds, a "
                          "record_mask, or a schedule with (T, ...) leaves")
     return int(leaves[0].shape[0])
+
+
+def lift_constants(fn: Callable, *example_args,
+                   data: tuple = ()) -> tuple[Callable, list]:
+    """Trace ``fn`` once; return ``(lifted, consts)`` such that
+    ``lifted(consts, *args)`` computes ``fn(*data, *args)``.
+
+    A jitted program bakes the arrays its function closes over into the
+    executable as constants. A recorder closes over the data matrix and its
+    node blocks, which at the paper's data sizes are gigabytes — past what
+    an executable can embed. Lifted, they enter the block program as
+    arguments, like the ``context``. ``data`` (arrays or
+    ``jax.ShapeDtypeStruct``s) are ``fn``'s leading arguments and lead
+    ``consts``: that is how a recorder is lifted from shapes alone.
+    """
+    closed, out_shape = jax.make_jaxpr(fn, return_shape=True)(
+        *data, *example_args)
+    out_tree = jax.tree.structure(out_shape)
+    n_data = len(jax.tree.leaves(data))
+
+    def lifted(consts, *args):
+        out = jcore.jaxpr_as_fun(
+            jcore.ClosedJaxpr(closed.jaxpr, consts[n_data:]))(
+                *consts[:n_data], *jax.tree.leaves(args))
+        return jax.tree.unflatten(out_tree, out)
+
+    return lifted, jax.tree.leaves(data) + list(closed.consts)
+
+
+def _round_slice_shapes(schedule: Any, stream: Callable | None) -> Any:
+    """Shapes of one round's schedule slice, streamed entries merged in."""
+    shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            np.shape(x)[1:], jax.dtypes.canonicalize_dtype(x.dtype)),
+        schedule)
+    if stream is not None:
+        shapes = {**shapes, **jax.eval_shape(stream, jnp.int32(0))}
+    return shapes
+
+
+def _record_shape_key(state: Any, schedule: Any) -> tuple:
+    """Hashable shapes of the state and of one round's schedule slice: what
+    the recorder's trace in ``lift_constants`` depends on. Cheap, since it
+    runs on every call, cache hits included."""
+    s_leaves, s_def = jax.tree.flatten(state)
+    k_leaves, k_def = jax.tree.flatten(schedule)
+    return (s_def, k_def,
+            tuple((np.shape(x), np.dtype(x.dtype)) for x in s_leaves),
+            tuple((np.shape(x)[1:], np.dtype(x.dtype)) for x in k_leaves))
+
+
+def block_program(step_fn: Callable[[Any, Any, Any], tuple[Any, Any]],
+                  lifted_rec: Callable | None = None, *,
+                  stop_fn: Callable | None = None,
+                  cadence: Any = None, ratio_fn: Callable | None = None,
+                  stream: Callable | None = None) -> Callable:
+    """The jitted block program ``run_round_blocks`` dispatches.
+
+    ``lifted_rec``: the recorder as ``lift_constants`` returns it (None:
+    no recording); its constants ride the context, which the program takes
+    as ``(context, recorder constants)``. The signature depends on the
+    arguments: ``(state, ctx, sched, rec)`` without a stop or a stream,
+    ``t_idx`` appended with a stream; ``((state, stopped), ctx, sched, rec
+    [, t_idx])`` with ``stop_fn``; ``((state, stopped, next, every), ctx,
+    sched, t_idx, force)`` with an adaptive ``cadence`` (which needs
+    ``ratio_fn``). The state is donated.
+    """
+    has_stop = stop_fn is not None
+    has_cadence = cadence is not None and lifted_rec is not None
+
+    # every block program takes ctx = (context, recorder constants)
+    def rec_call(s, sched_t, ctx):
+        return lifted_rec(ctx[1], s, sched_t)
+
+    def zero_row(s, sched_t, ctx):
+        sd = jax.eval_shape(rec_call, s, sched_t, ctx)
+        return jnp.zeros(sd.shape, sd.dtype)
+
+    def skip_step(s, ctx, sched_t):
+        # post-certification rounds are no-ops: state passes through
+        # untouched, which is what makes the stopped run's final state
+        # bitwise equal to the full run's state at the stop round
+        aux_sd = jax.eval_shape(lambda ss: step_fn(ss, ctx[0], sched_t)[1],
+                                s)
+        return s, jax.tree.map(
+            lambda sd: jnp.zeros(sd.shape, sd.dtype), aux_sd)
+
+    if has_cadence:
+        base = jnp.int32(cadence.base)
+        grow = jnp.int32(cadence.grow)
+        max_e = jnp.int32(cadence.max_every)
+        near = jnp.float32(cadence.near)
+
+        @partial(jax.jit, donate_argnums=(0,))
+        def run_block_adaptive(carry0, ctx, sched, t_idx, force):
+            def body(carry, xs):
+                s, stopped, nxt, every = carry
+                sched_t, t, force_t = xs
+                if stream is not None:
+                    sched_t = {**sched_t, **stream(t)}
+                s, aux = lax.cond(
+                    stopped, lambda ss: skip_step(ss, ctx, sched_t),
+                    lambda ss: step_fn(ss, ctx[0], sched_t), s)
+                due = jnp.logical_or(t >= nxt, force_t)
+                do_rec = jnp.logical_and(due, jnp.logical_not(stopped))
+                row = lax.cond(do_rec,
+                               lambda ss: rec_call(ss, sched_t, ctx),
+                               lambda ss: zero_row(ss, sched_t, ctx), s)
+                # geometric back-off while far from the stop threshold,
+                # snap to base inside the near band; the zero row of a
+                # non-record round is discarded by the where() gates
+                far = ratio_fn(row).astype(jnp.float32) > near
+                new_every = jnp.where(
+                    far, jnp.minimum(every * grow, max_e), base)
+                every = jnp.where(do_rec, new_every, every)
+                nxt = jnp.where(do_rec, t + new_every, nxt)
+                if stop_fn is not None:
+                    stop_now = jnp.logical_and(do_rec, stop_fn(row))
+                    stopped = jnp.logical_or(stopped, stop_now)
+                return (s, stopped, nxt, every), (aux, row, do_rec)
+            return lax.scan(body, carry0, (sched, t_idx, force))
+
+        return run_block_adaptive
+
+    if not has_stop:
+        if stream is None:
+            # historical engine: no stop carry, no cond around the
+            # step — byte-identical program to the pre-recorder
+            # executor, which is what keeps GapRecorder histories
+            # bitwise reproducible
+            @partial(jax.jit, donate_argnums=(0,))
+            def run_block(st, ctx, sched, rec):
+                def body(s, xs):
+                    sched_t, rec_t = xs
+                    s, aux = step_fn(s, ctx[0], sched_t)
+                    if lifted_rec is None:
+                        return s, (aux, None)
+                    row = lax.cond(rec_t,
+                                   lambda ss: rec_call(ss, sched_t, ctx),
+                                   lambda ss: zero_row(ss, sched_t, ctx), s)
+                    return s, (aux, row)
+                return lax.scan(body, st, (sched, rec))
+
+            return run_block
+
+        @partial(jax.jit, donate_argnums=(0,))
+        def run_block_streamed(st, ctx, sched, rec, t_idx):
+            def body(s, xs):
+                sched_t, rec_t, t = xs
+                sched_t = {**sched_t, **stream(t)}
+                s, aux = step_fn(s, ctx[0], sched_t)
+                if lifted_rec is None:
+                    return s, (aux, None)
+                row = lax.cond(rec_t,
+                               lambda ss: rec_call(ss, sched_t, ctx),
+                               lambda ss: zero_row(ss, sched_t, ctx), s)
+                return s, (aux, row)
+            return lax.scan(body, st, (sched, rec, t_idx))
+
+        return run_block_streamed
+
+    @partial(jax.jit, donate_argnums=(0,))
+    def run_block_stop(carry0, ctx, sched, rec, t_idx=None):
+        def body(carry, xs):
+            s, stopped = carry
+            if stream is None:
+                sched_t, rec_t = xs
+            else:
+                sched_t, rec_t, t = xs
+                sched_t = {**sched_t, **stream(t)}
+
+            s, aux = lax.cond(
+                stopped, lambda ss: skip_step(ss, ctx, sched_t),
+                lambda ss: step_fn(ss, ctx[0], sched_t), s)
+            do_rec = jnp.logical_and(rec_t, jnp.logical_not(stopped))
+            row = lax.cond(do_rec,
+                           lambda ss: rec_call(ss, sched_t, ctx),
+                           lambda ss: zero_row(ss, sched_t, ctx), s)
+            stop_now = jnp.logical_and(do_rec, stop_fn(row))
+            return (s, jnp.logical_or(stopped, stop_now)), \
+                (aux, row, do_rec)
+        xs = (sched, rec) if stream is None else (sched, rec, t_idx)
+        return lax.scan(body, carry0, xs)
+
+    return run_block_stop
 
 
 def run_round_blocks(step_fn: Callable[[Any, Any, Any], tuple[Any, Any]],
@@ -404,7 +590,6 @@ def run_round_blocks(step_fn: Callable[[Any, Any, Any], tuple[Any, Any]],
     uses_sched = bool(getattr(recorder, "uses_schedule", False))
     has_cadence = cadence is not None and record_fn is not None
     if has_cadence:
-        ratio_fn = recorder.cadence_ratio  # required by the contract
         cache_key = (None if cache_key is None
                      else (cache_key, cadence.cache_token()))
     if record_fn is not None and record_mask is None and not has_cadence:
@@ -413,124 +598,12 @@ def run_round_blocks(step_fn: Callable[[Any, Any, Any], tuple[Any, Any]],
                if record_fn is not None and not has_cadence
                else np.zeros((t_total,), dtype=bool))
     has_stop = stop_fn is not None
-
-    def build():
-        def rec_call(s, sched_t):
-            return record_fn(s, sched_t) if uses_sched else record_fn(s)
-
-        def zero_row(s, sched_t):
-            # shape-only evaluation, re-derived per trace so a cached driver
-            # stays correct if it is reused at different state shapes
-            sd = jax.eval_shape(rec_call, s, sched_t)
-            return jnp.zeros(sd.shape, sd.dtype)
-
-        def skip_step(s, ctx, sched_t):
-            # post-certification rounds are no-ops: state passes through
-            # untouched, which is what makes the stopped run's final state
-            # bitwise equal to the full run's state at the stop round
-            aux_sd = jax.eval_shape(lambda ss: step_fn(ss, ctx, sched_t)[1],
-                                    s)
-            return s, jax.tree.map(
-                lambda sd: jnp.zeros(sd.shape, sd.dtype), aux_sd)
-
-        if has_cadence:
-            base = jnp.int32(cadence.base)
-            grow = jnp.int32(cadence.grow)
-            max_e = jnp.int32(cadence.max_every)
-            near = jnp.float32(cadence.near)
-
-            @partial(jax.jit, donate_argnums=(0,))
-            def run_block_adaptive(carry0, ctx, sched, t_idx, force):
-                def body(carry, xs):
-                    s, stopped, nxt, every = carry
-                    sched_t, t, force_t = xs
-                    if stream is not None:
-                        sched_t = {**sched_t, **stream(t)}
-                    s, aux = lax.cond(
-                        stopped, lambda ss: skip_step(ss, ctx, sched_t),
-                        lambda ss: step_fn(ss, ctx, sched_t), s)
-                    due = jnp.logical_or(t >= nxt, force_t)
-                    do_rec = jnp.logical_and(due, jnp.logical_not(stopped))
-                    row = lax.cond(do_rec,
-                                   lambda ss: rec_call(ss, sched_t),
-                                   lambda ss: zero_row(ss, sched_t), s)
-                    # geometric back-off while far from the stop threshold,
-                    # snap to base inside the near band; the zero row of a
-                    # non-record round is discarded by the where() gates
-                    far = ratio_fn(row).astype(jnp.float32) > near
-                    new_every = jnp.where(
-                        far, jnp.minimum(every * grow, max_e), base)
-                    every = jnp.where(do_rec, new_every, every)
-                    nxt = jnp.where(do_rec, t + new_every, nxt)
-                    if stop_fn is not None:
-                        stop_now = jnp.logical_and(do_rec, stop_fn(row))
-                        stopped = jnp.logical_or(stopped, stop_now)
-                    return (s, stopped, nxt, every), (aux, row, do_rec)
-                return lax.scan(body, carry0, (sched, t_idx, force))
-
-            return run_block_adaptive
-
-        if not has_stop:
-            if stream is None:
-                # historical engine: no stop carry, no cond around the
-                # step — byte-identical program to the pre-recorder
-                # executor, which is what keeps GapRecorder histories
-                # bitwise reproducible
-                @partial(jax.jit, donate_argnums=(0,))
-                def run_block(st, ctx, sched, rec):
-                    def body(s, xs):
-                        sched_t, rec_t = xs
-                        s, aux = step_fn(s, ctx, sched_t)
-                        if record_fn is None:
-                            return s, (aux, None)
-                        row = lax.cond(rec_t,
-                                       lambda ss: rec_call(ss, sched_t),
-                                       lambda ss: zero_row(ss, sched_t), s)
-                        return s, (aux, row)
-                    return lax.scan(body, st, (sched, rec))
-
-                return run_block
-
-            @partial(jax.jit, donate_argnums=(0,))
-            def run_block_streamed(st, ctx, sched, rec, t_idx):
-                def body(s, xs):
-                    sched_t, rec_t, t = xs
-                    sched_t = {**sched_t, **stream(t)}
-                    s, aux = step_fn(s, ctx, sched_t)
-                    if record_fn is None:
-                        return s, (aux, None)
-                    row = lax.cond(rec_t,
-                                   lambda ss: rec_call(ss, sched_t),
-                                   lambda ss: zero_row(ss, sched_t), s)
-                    return s, (aux, row)
-                return lax.scan(body, st, (sched, rec, t_idx))
-
-            return run_block_streamed
-
-        @partial(jax.jit, donate_argnums=(0,))
-        def run_block_stop(carry0, ctx, sched, rec, t_idx=None):
-            def body(carry, xs):
-                s, stopped = carry
-                if stream is None:
-                    sched_t, rec_t = xs
-                else:
-                    sched_t, rec_t, t = xs
-                    sched_t = {**sched_t, **stream(t)}
-
-                s, aux = lax.cond(
-                    stopped, lambda ss: skip_step(ss, ctx, sched_t),
-                    lambda ss: step_fn(ss, ctx, sched_t), s)
-                do_rec = jnp.logical_and(rec_t, jnp.logical_not(stopped))
-                row = lax.cond(do_rec,
-                               lambda ss: rec_call(ss, sched_t),
-                               lambda ss: zero_row(ss, sched_t), s)
-                stop_now = jnp.logical_and(do_rec, stop_fn(row))
-                return (s, jnp.logical_or(stopped, stop_now)), \
-                    (aux, row, do_rec)
-            xs = (sched, rec) if stream is None else (sched, rec, t_idx)
-            return lax.scan(body, carry0, xs)
-
-        return run_block_stop
+    if record_fn is not None and cache_key is not None:
+        # the recorder is traced for these shapes (``lift_constants``): a
+        # cached program is reused only where that trace fits (the stream's
+        # shapes follow from its fingerprint, already in the key)
+        cache_key = (cache_key, ("record-shapes",
+                                 _record_shape_key(state, schedule)))
 
     # phase tracing (repro.obs.trace): the active tracer records the driver
     # build (trace time — runs only on a cache miss/bypass) and every block
@@ -542,9 +615,23 @@ def run_round_blocks(step_fn: Callable[[Any, Any, Any], tuple[Any, Any]],
 
     def timed_build():
         with tracer.span("driver-build", key=cache_key is not None):
-            return build()
+            lifted_rec, rec_consts = None, []
+            if record_fn is not None:
+                # a cache hit reuses these arrays: the content-addressed key
+                # makes them equal to the ones this call's recorder holds
+                lifted_rec, rec_consts = lift_constants(
+                    lambda s, sched_t: (record_fn(s, sched_t) if uses_sched
+                                        else record_fn(s)),
+                    state, _round_slice_shapes(schedule, stream))
+            program = block_program(
+                step_fn, lifted_rec, stop_fn=stop_fn,
+                cadence=cadence if has_cadence else None,
+                ratio_fn=recorder.cadence_ratio if has_cadence else None,
+                stream=stream)
+            return program, lifted_rec, rec_consts
 
-    run_block = cached_driver(cache_key, timed_build)
+    run_block, lifted_rec, rec_consts = cached_driver(cache_key, timed_build)
+    ctx_arg = (context, rec_consts)
 
     rows, valids, auxes = [], [], []
     start = 0
@@ -574,11 +661,11 @@ def run_round_blocks(step_fn: Callable[[Any, Any, Any], tuple[Any, Any]],
                     force_b = jnp.asarray(
                         np.arange(start, stop) == t_total - 1)
                     carry, (aux_b, rows_b, valid_b) = run_block(
-                        carry, context, sched_b, t_b, force_b)
+                        carry, ctx_arg, sched_b, t_b, force_b)
                     state, stop_flag = carry[0], carry[1]
                     valids.append(valid_b)
                 elif has_stop:
-                    args = ((state, stop_flag), context, sched_b,
+                    args = ((state, stop_flag), ctx_arg, sched_b,
                             jnp.asarray(rec_all[start:stop]))
                     if stream is not None:
                         args += (jnp.arange(start, stop, dtype=jnp.int32),)
@@ -586,7 +673,7 @@ def run_round_blocks(step_fn: Callable[[Any, Any, Any], tuple[Any, Any]],
                         run_block(*args)
                     valids.append(valid_b)
                 else:
-                    args = (state, context, sched_b,
+                    args = (state, ctx_arg, sched_b,
                             jnp.asarray(rec_all[start:stop]))
                     if stream is not None:
                         args += (jnp.arange(start, stop, dtype=jnp.int32),)
@@ -615,16 +702,8 @@ def run_round_blocks(step_fn: Callable[[Any, Any, Any], tuple[Any, Any]],
                                      axis=0)[valid]
             rounds = np.nonzero(valid)[0]
         else:  # T == 0: empty history, same as the loop drivers
-            if uses_sched:
-                sched0 = jax.tree.map(
-                    lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
-                    schedule)
-                if stream is not None:
-                    sched0 = {**sched0,
-                              **jax.eval_shape(stream, jnp.int32(0))}
-                row_sd = jax.eval_shape(record_fn, state, sched0)
-            else:
-                row_sd = jax.eval_shape(record_fn, state)
+            row_sd = jax.eval_shape(lifted_rec, rec_consts, state,
+                                    _round_slice_shapes(schedule, stream))
             metrics = np.zeros((0,) + row_sd.shape, row_sd.dtype)
             rounds = np.zeros((0,), dtype=np.int64)
         if stopped_early and rounds.size:

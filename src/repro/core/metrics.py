@@ -57,6 +57,7 @@ from repro.core.duality import (block_spectral_norms, certificate_thresholds,
                                 consensus_residual, gap_report, neighbor_mask,
                                 neighborhood_mean, node_subproblem_gaps)
 from repro.core.partition import Partition
+from repro.core.precision import MATMUL
 
 GAP_METRICS = ("primal", "hamiltonian", "dual", "gap", "consensus_violation")
 # append-only: downstream code indexes the first five by name, and new
@@ -308,11 +309,13 @@ class CertificateRecorder:
         data never reaches the cohort)."""
         if honest is None:
             v_sum = jnp.sum(v_stack, axis=0)
-            ax_sum = jnp.einsum("kdn,kn->d", a_parts, x_parts)
+            ax_sum = jnp.einsum("kdn,kn->d", a_parts, x_parts,
+                                precision=MATMUL)
         else:
             h = honest.astype(v_stack.dtype)
             v_sum = jnp.sum(h[:, None] * v_stack, axis=0)
-            ax_sum = jnp.einsum("kdn,kn,k->d", a_parts, x_parts, h)
+            ax_sum = jnp.einsum("kdn,kn,k->d", a_parts, x_parts, h,
+                                precision=MATMUL)
         return jnp.stack([v_sum, ax_sum])
 
     def record_fn(self, state, sched=None) -> jax.Array:

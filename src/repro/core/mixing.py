@@ -29,18 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-
-def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with a fallback to the pre-0.5 experimental API.
-
-    The installed jax (0.4.x) only ships ``jax.experimental.shard_map``;
-    newer releases promote it to ``jax.shard_map``. Every shard_map user in
-    this repo goes through this shim so the mesh paths work on both.
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+from repro.core.precision import MATMUL
 
 
 def dense_mix(w: jax.Array, v_stack: jax.Array) -> jax.Array:
@@ -51,7 +40,7 @@ def dense_mix(w: jax.Array, v_stack: jax.Array) -> jax.Array:
       v_stack: (K, ...) per-node state stacked on axis 0.
     """
     flat = v_stack.reshape(v_stack.shape[0], -1)
-    out = w.astype(flat.dtype) @ flat
+    out = jnp.matmul(w.astype(flat.dtype), flat, precision=MATMUL)
     return out.reshape(v_stack.shape)
 
 
@@ -70,7 +59,7 @@ def mix_power(w: jax.Array, v_stack: jax.Array, steps: int) -> jax.Array:
         return dense_mix(w, v_stack)
     w_pow = w
     for _ in range(steps - 1):
-        w_pow = w @ w_pow
+        w_pow = jnp.matmul(w, w_pow, precision=MATMUL)
     return dense_mix(w_pow, v_stack)
 
 
@@ -175,9 +164,7 @@ def ring_mix_ppermute(v_local: jax.Array, axis_name: str, weights: jax.Array,
       weights: (2*conn+1,) band [w_{-conn}, ..., w_0, ..., w_{+conn}].
       conn: connectivity (1 = ring, 2 = 2-connected cycle, ...).
     """
-    # lax.axis_size only exists on newer jax; psum of 1 is the portable spelling
-    k = (lax.axis_size(axis_name) if hasattr(lax, "axis_size")
-         else lax.psum(1, axis_name))
+    k = lax.axis_size(axis_name)
     out = weights[conn] * v_local
     for off in range(1, conn + 1):
         # receive from left neighbor at distance `off`
@@ -329,7 +316,7 @@ def _gate_center_flags(vals, mask, self_hot, counts, trim):
     # the linear mix bit-for-bit.
     norms = jnp.sqrt(jnp.sum(vals * vals, axis=-1))          # (R, K)
     cnorm = jnp.sqrt(jnp.sum(center * center, axis=-1))      # (R,)
-    dots = jnp.einsum("rkd,rd->rk", vals, center)
+    dots = jnp.einsum("rkd,rd->rk", vals, center, precision=MATMUL)
     cos = dots / (norms * cnorm[:, None] + 1e-30)
     nb_mask = mask & ~self_hot
     m_nb = jnp.sum(nb_mask.astype(jnp.int32), axis=1)
@@ -505,7 +492,8 @@ def robust_neighborhood_mix(w_rows: jax.Array, buf: jax.Array,
             clamped = jnp.where(flagged[:, :, None],
                                 center[:, None, :], vals)
             out = jnp.einsum("rk,rkd->rd", w_rows,
-                             jnp.where(mask[:, :, None], clamped, 0.0))
+                             jnp.where(mask[:, :, None], clamped, 0.0),
+                             precision=MATMUL)
         else:
             # "trim": drop the flagged edges for this step and move their
             # weight onto the self term — a gated trimmed W-mean. Unlike
@@ -514,7 +502,8 @@ def robust_neighborhood_mix(w_rows: jax.Array, buf: jax.Array,
             w_eff = jnp.where(flagged, 0.0, w_rows)
             w_drop = jnp.sum(jnp.where(flagged, w_rows, 0.0), axis=1)
             out = jnp.einsum("rk,rkd->rd", w_eff,
-                             jnp.where(mask[:, :, None], vals, 0.0))
+                             jnp.where(mask[:, :, None], vals, 0.0),
+                             precision=MATMUL)
             out = out + w_drop[:, None] * self_vals
         return out.reshape((r,) + buf.shape[1:])
 
@@ -523,7 +512,7 @@ def robust_neighborhood_mix(w_rows: jax.Array, buf: jax.Array,
                                 jnp.asarray(row_ids), clip, flat.dtype)
     clipped = self_vals[:, None, :] + dev * scale[:, :, None]
     clipped = jnp.where(mask[:, :, None], clipped, 0.0)
-    out = jnp.einsum("rk,rkd->rd", w_rows, clipped)
+    out = jnp.einsum("rk,rkd->rd", w_rows, clipped, precision=MATMUL)
     return out.reshape((r,) + buf.shape[1:])
 
 

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.precision import MATMUL
+
 
 @dataclasses.dataclass(frozen=True)
 class Problem:
@@ -65,11 +67,12 @@ class Problem:
 
     def objective(self, x: jax.Array) -> jax.Array:
         """F_A(x) = f(Ax) + g(x)."""
-        return self.f(self.a @ x) + self.g(x)
+        return self.f(jnp.matmul(self.a, x, precision=MATMUL)) + self.g(x)
 
     def dual_objective(self, w: jax.Array) -> jax.Array:
         """F_B(w) = f*(w) + sum_i g_i*(-A_i^T w)  (problem (B))."""
-        return self.f_conj(w) + jnp.sum(self.g_conj_el(-(self.a.T @ w), self.g_params()))
+        atw = jnp.matmul(self.a.T, w, precision=MATMUL)
+        return self.f_conj(w) + jnp.sum(self.g_conj_el(-atw, self.g_params()))
 
 
 # ---------------------------------------------------------------------------

@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.core.precision import MATMUL
+
 # VMEM we allow the cached (n_k, n_k) Gram block to occupy per node. A TPU
 # core has ~16 MB of VMEM which the block shares with dx/h/x/scalars; half
 # of it keeps headroom for double-buffered loads.
@@ -60,7 +62,7 @@ def gram_pays(d: int, n_k: int, itemsize: int = 4,
 
 def block_gram(a_parts: jax.Array) -> jax.Array:
     """(K, d, n_k) column blocks -> (K, n_k, n_k) node-local Gram blocks."""
-    return jnp.einsum("kdn,kdm->knm", a_parts, a_parts)
+    return jnp.einsum("kdn,kdm->knm", a_parts, a_parts, precision=MATMUL)
 
 
 class SubproblemSpec(NamedTuple):
@@ -75,8 +77,8 @@ def eval_subproblem(problem, spec: SubproblemSpec, a_k: jax.Array,
                     grad_k: jax.Array, gp_k: jax.Array,
                     mask_k: jax.Array) -> jax.Array:
     """Evaluate G_k^{sigma'}(dx; v_k, x_k) for one node (used in tests/Theta probes)."""
-    r = a_k @ dx_k
-    lin = jnp.dot(grad_k, r)
+    r = jnp.matmul(a_k, dx_k, precision=MATMUL)
+    lin = jnp.dot(grad_k, r, precision=MATMUL)
     quad = 0.5 * spec.sigma_over_tau * jnp.sum(r ** 2)
     g_term = jnp.sum(problem.g_el(x_k + dx_k, gp_k) * mask_k)
     return spec.inv_k * problem.f(v_k) + lin + quad + g_term
@@ -116,7 +118,8 @@ def cd_solve(problem, spec: SubproblemSpec, a_k: jax.Array, x_k: jax.Array,
         dx, r = carry
         a_i = lax.dynamic_index_in_dim(a_k, i, axis=1, keepdims=False)
         z = x_k[i] + dx[i]
-        grad_i = jnp.dot(a_i, grad_k + spec.sigma_over_tau * r)
+        grad_i = jnp.dot(a_i, grad_k + spec.sigma_over_tau * r,
+                         precision=MATMUL)
         step = 1.0 / q_safe[i]
         z_new = problem.prox_g_el(z - grad_i * step, step, gp_k[i])
         ok = (q[i] > 0) & (mask_k[i] > 0)
@@ -188,7 +191,7 @@ def cd_solve_all(problem, spec: SubproblemSpec, a_parts: jax.Array,
     (numerically equivalent up to float reassociation, see module docstring).
     """
     if gram_parts is not None:
-        atg = jnp.einsum("kdn,kd->kn", a_parts, grads)
+        atg = jnp.einsum("kdn,kd->kn", a_parts, grads, precision=MATMUL)
         if step_budgets is None:
             fn = lambda g_k, c_k, x_k, gp_k, m_k: cd_solve_gram(
                 problem, spec, g_k, c_k, x_k, gp_k, m_k, num_steps)

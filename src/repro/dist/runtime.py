@@ -73,9 +73,10 @@ from repro.core.cola import (ColaConfig, RunResult,
 from repro.core.duality import consensus_residual, neighborhood_mean
 from repro.core.partition import make_partition
 from repro.core.problems import Problem
-from repro.dist.sharding import (block_payload_pspec, cola_counters_pspecs,
-                                 cola_env_pspecs, cola_recorder_pspecs,
-                                 cola_state_pspecs, plan_payload_pspecs)
+from repro.dist.sharding import (auto_mesh, block_payload_pspec,
+                                 cola_counters_pspecs, cola_env_pspecs,
+                                 cola_recorder_pspecs, cola_state_pspecs,
+                                 plan_payload_pspecs)
 
 
 def _dist_mixers(axis: str, local_nodes: int, conn: int, comm: str,
@@ -489,8 +490,8 @@ def _certificate_dist_record(rec, mesh, axis: str, local_nodes: int,
                              pmax=lambda s: lax.pmax(s, axis))
 
     node, repl = P(axis), P()
-    shard = mixing.shard_map(
-        body, mesh,
+    shard = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(node, node, node, node, node, node, repl, repl),
         out_specs=P())
 
@@ -635,6 +636,7 @@ def run_dist_cola(problem: Problem, graph: topo.Topology, cfg: ColaConfig,
     _check_wire_config(cfg, attacks=attacks, leave_mode=leave_mode,
                        dist=True)
     quantized = quant.is_quantized(cfg.wire)
+    mesh = auto_mesh(mesh)
     axis = axis or mesh.axis_names[0]
     m = dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
     k = graph.num_nodes
@@ -814,8 +816,8 @@ def run_dist_cola(problem: Problem, graph: topo.Topology, cfg: ColaConfig,
         payload_spec = plan_payload_pspecs(axis)
     # attack entries are per-node (K,)-rows (the (T, K, d) bias slices to
     # (K, d)) — they shard over the node axis like the state they corrupt
-    shard_step = mixing.shard_map(
-        shard_round, mesh,
+    shard_step = jax.shard_map(
+        shard_round, mesh=mesh,
         in_specs=(state_spec, env_spec, payload_spec, node,
                   node if has_budget else repl,
                   node if has_reset else repl, repl,

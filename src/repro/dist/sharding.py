@@ -22,7 +22,7 @@ import dataclasses
 from typing import Any
 
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 
 @dataclasses.dataclass(frozen=True)
@@ -185,6 +185,18 @@ def batch_pspecs(cfg, shape, axes: MeshAxes) -> Any:
 # ---------------------------------------------------------------------------
 # CoLA state (Algorithm 1) — the node axis onto a mesh axis
 # ---------------------------------------------------------------------------
+
+def auto_mesh(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis Auto-typed: the same devices and names.
+
+    ``jax.make_mesh`` types its axes Explicit, which puts each array's
+    sharding into its type and refuses a ``vmap`` whose operands shard the
+    mapped axis differently. The CoLA runtime places every node-axis buffer
+    itself and leaves the global recompute outside ``shard_map``
+    (recorders, telemetry) to GSPMD, so it runs on this view."""
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
 
 def cola_state_pspecs(axis: str) -> Any:
     """Specs for ``ColaState``: both buffers (``x_parts`` (K, n_k) and

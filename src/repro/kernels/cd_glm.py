@@ -1,8 +1,22 @@
-"""Pallas TPU kernel for the CoLA local subproblem solver (paper Eq. 1-2).
+"""Pallas kernels for the CoLA local subproblem solver (paper Eq. 1-2).
 
-The paper's wall-clock is dominated by the Theta-approximate local solve
-(Fig. 1b communication/computation trade-off). On TPU we keep the whole
-node-local working set in VMEM for all ``kappa * n_k`` coordinate updates:
+They run in interpret mode only. The TPU compiler refuses both for a v5e,
+for two reasons:
+
+  * the ``(1, n_k)`` / ``(1, d)`` block specs on ``(K, n_k)`` / ``(K, d)``
+    arrays break the (8, 128) tiling rule: the last two dimensions of a
+    block must divide by 8 and 128 or equal the array's;
+  * with ``(K, 1, n)`` arrays and ``(1, 1, n)`` blocks instead, Mosaic
+    has no lowering for the ``dynamic_slice`` that
+    ``lax.dynamic_slice_in_dim`` makes of a column of a loaded value.
+
+Compiling them means rewriting them. ``run_cola`` uses the pure-jnp
+``repro.core.subproblem.cd_solve_all`` instead.
+
+The design they were written to: the paper's wall-clock is dominated by the
+Theta-approximate local solve (Fig. 1b communication/computation
+trade-off), so the whole node-local working set stays in VMEM for all
+``kappa * n_k`` coordinate updates:
 
   * the node's column block A_[k]  (d x n_k tile),
   * the residual  r = A_[k] dx     (d,),

@@ -96,8 +96,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
       q: (B, Sq, H, hd); k, v: (B, Skv, KV, hd), H = G * KV.
       q_pos: (B, Sq) int32; kv_pos: (B, Skv) int32, -1 = empty slot.
 
-    ``interpret=True`` executes the kernel body on CPU (this container);
-    on TPU pass interpret=False.
+    ``interpret=True`` executes the kernel body with the Pallas
+    interpreter; on a TPU pass ``interpret=False`` (``ops.flash_attention_ops``
+    chooses by backend).
     """
     b, sq, h, hd = q.shape
     skv, kvh = k.shape[1], k.shape[2]

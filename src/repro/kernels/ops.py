@@ -6,8 +6,11 @@
 * ``flash_attention_ops`` — drop-in for
   ``repro.models.attention.chunked_attention``.
 
-Both run the kernel body in interpret mode on CPU (this container) and as a
-compiled Mosaic kernel on TPU.
+Both interpret the kernel body off the TPU and compile it with Mosaic on a
+TPU (``interpret_mode``); neither can be told to interpret on a TPU. The
+flash-attention kernel compiles for a v5e. The TPU compiler refuses both
+cd_glm kernels (see ``repro.kernels.cd_glm``), so on a chip
+``cd_solve_pallas`` raises that error; ``run_cola`` never calls it.
 """
 from __future__ import annotations
 
@@ -17,14 +20,19 @@ import jax.numpy as jnp
 from repro.core.partition import Partition
 from repro.core.problems import Problem
 from repro.core.subproblem import SubproblemSpec, gram_pays
-from repro.kernels import cd_glm, flash_attention as fa
+from repro.kernels import cd_glm
+from repro.kernels.flash_attention import flash_attention
+
+
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run interpreted: everywhere but a TPU."""
+    return jax.default_backend() != "tpu"
 
 
 def cd_solve_pallas(problem: Problem, spec: SubproblemSpec,
                     a_parts: jax.Array, x_parts: jax.Array,
                     grads: jax.Array, gp_parts: jax.Array,
                     masks: jax.Array, num_steps: int, *,
-                    interpret: bool = True,
                     gram_parts: jax.Array | None = None,
                     cd_mode: str = "residual") -> jax.Array:
     """Same signature/semantics as ``cd_solve_all`` but on the Pallas kernel.
@@ -47,17 +55,19 @@ def cd_solve_pallas(problem: Problem, spec: SubproblemSpec,
         return cd_glm.cd_solve_blocks_gram(
             gram_parts, x_parts, atg, gp_parts, masks,
             num_steps=num_steps, sigma_over_tau=float(spec.sigma_over_tau),
-            l1=float(l1), l2=float(l2), box=float(box), interpret=interpret)
+            l1=float(l1), l2=float(l2), box=float(box),
+            interpret=interpret_mode())
     return cd_glm.cd_solve_blocks(
         a_parts, x_parts, grads, gp_parts, masks,
         num_steps=num_steps, sigma_over_tau=float(spec.sigma_over_tau),
-        l1=float(l1), l2=float(l2), box=float(box), interpret=interpret)
+        l1=float(l1), l2=float(l2), box=float(box),
+        interpret=interpret_mode())
 
 
 def flash_attention_ops(q, k, v, q_pos, kv_pos, *, mode: str,
                         window: int = 0, block_q: int = 128,
-                        block_kv: int = 128, interpret: bool = True):
+                        block_kv: int = 128):
     """Drop-in for chunked_attention (same argument convention)."""
-    return fa.flash_attention(q, k, v, q_pos, kv_pos, mode=mode,
-                              window=window, block_q=block_q,
-                              block_kv=block_kv, interpret=interpret)
+    return flash_attention(q, k, v, q_pos, kv_pos, mode=mode, window=window,
+                           block_q=block_q, block_kv=block_kv,
+                           interpret=interpret_mode())

@@ -300,9 +300,8 @@ def _gossip_train_artifacts(cfg: ModelConfig, shape: InputShape, mesh, axes,
                     + 0.5 * other.astype(jnp.float32)).astype(p_local.dtype)
         return jax.tree.map(mix_leaf, params_stacked)
 
-    from repro.core import mixing
-    shard_mix = mixing.shard_map(mix_params, mesh, in_specs=P("pod"),
-                                 out_specs=P("pod"))
+    shard_mix = jax.shard_map(mix_params, mesh=mesh, in_specs=P("pod"),
+                              out_specs=P("pod"))
 
     def gossip_step(states, batches):
         new_states, metrics = jax.vmap(local_step)(states, batches)

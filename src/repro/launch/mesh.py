@@ -11,10 +11,27 @@ import jax
 
 from repro.dist.sharding import MeshAxes
 
-# TPU v5e hardware constants used by the roofline analysis.
-PEAK_FLOPS_BF16 = 197e12      # per chip
-HBM_BW = 819e9                # bytes/s per chip
-ICI_BW = 50e9                 # bytes/s per link (~per direction)
+# Published peaks of one chip, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, HBM at
+# 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect over four ICI links
+# (50 GB/s each).
+CHIP_PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9,
+                    "ici_link_bytes_per_s": 50e9},
+}
+# the chip the production meshes below are laid out for
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """Published peaks of one ``device_kind`` chip. A kind missing from
+    ``CHIP_PEAKS`` is an error, never a default."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(CHIP_PEAKS)})") from None
 
 
 def make_production_mesh(*, multi_pod: bool = False):

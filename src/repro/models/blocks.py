@@ -25,13 +25,11 @@ def _attention(cfg, q, k, v, q_pos, kv_pos, *, mode):
     """Backend dispatch: jnp chunked scan (oracle) or the Pallas kernel
     (VMEM-resident tiles; interpret mode on CPU, Mosaic on TPU)."""
     if cfg.attn_backend == "pallas":
-        from repro.kernels.flash_attention import flash_attention
-        import jax as _jax
-        return flash_attention(
+        from repro.kernels.ops import flash_attention_ops
+        return flash_attention_ops(
             q, k, v, q_pos, kv_pos, mode=mode, window=cfg.window,
             block_q=min(128, max(8, q.shape[1])),
-            block_kv=min(128, max(8, k.shape[1])),
-            interpret=_jax.default_backend() != "tpu")
+            block_kv=min(128, max(8, k.shape[1])))
     return chunked_attention(q, k, v, q_pos, kv_pos, mode=mode,
                              window=cfg.window, kv_chunk=cfg.scan_chunk,
                              compute_dtype=cfg.attn_compute_dtype)

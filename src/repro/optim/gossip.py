@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import executor as exec_engine, metrics as metrics_lib, \
     mixing, quant, topology as topo
@@ -165,13 +165,28 @@ def _param_mixer(gcfg: GossipConfig, mesh, axis: str | None,
                 return out
             return mix_pytree(w, params, gcfg.gossip_steps)
         band = mixing.banded_weights(w, conn or 1)
-        shard = mixing.shard_map(
+        shard = jax.shard_map(
             lambda p: ring_mix_pytree(p, axis, band, conn or 1,
                                       gcfg.gossip_steps),
-            mesh, in_specs=P(axis), out_specs=P(axis))
+            mesh=mesh, in_specs=P(axis), out_specs=P(axis))
         return shard(params)
 
     return mix
+
+
+def _node_mesh(mesh, axis):
+    """``(mesh, place)`` for the ring path: the Auto-typed view of the
+    caller's mesh (see ``dist.sharding.auto_mesh`` — the local step is
+    vmapped over replicas and batches that the model code does not
+    constrain) and a function laying node-stacked pytrees out over
+    ``axis`` on it. Without a mesh, ``place`` is the identity."""
+    if mesh is None:
+        return None, lambda tree: tree
+    from repro.dist.sharding import auto_mesh
+    mesh = auto_mesh(mesh)
+    sh = NamedSharding(mesh, P(axis))
+    return mesh, lambda tree: jax.tree.map(
+        lambda x: jax.device_put(x, sh), tree)
 
 
 def make_gossip_step(local_step: Callable, gcfg: GossipConfig, *,
@@ -195,6 +210,7 @@ def make_gossip_step(local_step: Callable, gcfg: GossipConfig, *,
     key schedule stays reproducible, and account one
     ``dp.releases_per_mix_round(...)`` batch per mixed round).
     """
+    mesh, place = _node_mesh(mesh, axis)
     mix_params = _param_mixer(gcfg, mesh, axis, conn, dp)
     base_key = None if dp is None else jax.random.PRNGKey(dp.seed)
 
@@ -215,7 +231,16 @@ def make_gossip_step(local_step: Callable, gcfg: GossipConfig, *,
         return new_states._replace(
             params=mix_params(w, new_states.params, key)), metrics
 
-    return jax.jit(step, static_argnames=("do_mix",))
+    jitted = jax.jit(step, static_argnames=("do_mix",))
+    if mesh is None:
+        return jitted
+
+    def placed_step(states, batches, w, active, do_mix=True, dp_round=0):
+        states, batches, active = place((states, batches, active))
+        return jitted(states, batches, w, active, do_mix=do_mix,
+                      dp_round=dp_round)
+
+    return placed_step
 
 
 def mix_schedule(rounds: int, mix_every: int) -> np.ndarray:
@@ -266,6 +291,7 @@ def make_gossip_block_runner(local_step: Callable, gcfg: GossipConfig, *,
     noise — plus a ``dp`` summary dict (final epsilon/rho/releases).
     NOTE: ``states`` buffers are donated — do not reuse the argument.
     """
+    mesh, place = _node_mesh(mesh, axis)
     mix_params = _param_mixer(gcfg, mesh, axis, conn, dp)
     base_key = None if dp is None else jax.random.PRNGKey(dp.seed)
     if telemetry and recorder is None:
@@ -289,6 +315,7 @@ def make_gossip_block_runner(local_step: Callable, gcfg: GossipConfig, *,
 
     def run(states, batches, w, active, mix, *, block_size: int = 32,
             record_mask=None):
+        states = place(states)
         sched = {"batch": batches, "w": w, "active": active, "mix": mix}
         if dp is not None:
             # per-round key index: noise draws are a function of the round,

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core import mixing, quant
+from repro.core.precision import MATMUL
 from repro.topo.plan import BlockPlan, CommPlan
 
 
@@ -124,7 +125,7 @@ def block_mix_step(v_block, axis_name: str, plan: BlockPlan, w_rows):
     """
     flat = v_block.reshape(v_block.shape[0], -1)
     buf = block_gather_neighbors(flat, axis_name, plan)
-    out = w_rows.astype(flat.dtype) @ buf
+    out = jnp.matmul(w_rows.astype(flat.dtype), buf, precision=MATMUL)
     return out.reshape(v_block.shape)
 
 
@@ -270,7 +271,8 @@ def block_qmix_steps(v_block, ef_block, axis_name: str, plan: BlockPlan,
                 ef = (p - quant.dequantize(q, sc)).reshape(ef.shape)
         deq = quant.dequantize(q, sc)
         buf = block_gather_neighbors_q(q, sc, deq, axis_name, plan)
-        out = (w_rows.astype(deq.dtype) @ buf).reshape(out.shape)
+        out = jnp.matmul(w_rows.astype(deq.dtype), buf,
+                         precision=MATMUL).reshape(out.shape)
     return out, ef
 
 

@@ -49,6 +49,7 @@ from typing import Tuple
 import numpy as np
 
 from repro.core import topology as topo
+from repro.core.precision import MATMUL
 from repro.topo import coloring
 
 Edge = coloring.Edge
@@ -706,7 +707,7 @@ def block_mix_dense(plan: BlockPlan, w, v_stack, *, check: bool = True):
                 buf = buf.at[src * ln:(src + 1) * ln].set(
                     flat[src * ln:(src + 1) * ln])
         w_rows = jnp.asarray(w[dev * ln:(dev + 1) * ln], dtype=flat.dtype)
-        outs.append(w_rows @ buf)
+        outs.append(jnp.matmul(w_rows, buf, precision=MATMUL))
     return jnp.concatenate(outs, axis=0).reshape(v_stack.shape)
 
 

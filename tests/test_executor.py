@@ -159,6 +159,30 @@ def test_executor_early_stop_truncates_and_freezes_state():
     np.testing.assert_allclose(res.metrics[:, 0], np.arange(1.0, 8.0))
 
 
+@pytest.mark.parametrize("recorder", ["gap", "gap+certificate"])
+def test_recorder_arrays_are_block_program_arguments(recorder):
+    """The arrays a recorder closes over (the data matrix, its node blocks)
+    enter the block program as arguments: at the paper's data sizes an
+    executable cannot embed them as constants."""
+    import warnings
+
+    x, y, _ = synthetic.regression(1024, 256, seed=4, sparsity_solution=0.2)
+    prob = problems.lasso(jnp.asarray(x), jnp.asarray(y), 1e-2)  # A: 1 MiB
+    limit = jax.config.jax_captured_constants_warn_bytes
+    jax.config.update("jax_captured_constants_warn_bytes", 64 * 1024)
+    try:
+        with warnings.catch_warnings(record=True) as caught:
+            warnings.simplefilter("always")
+            res = run_cola(prob, topo.ring(K), ColaConfig(), 6,
+                           recorder=recorder, eps=1e-9)
+    finally:
+        jax.config.update("jax_captured_constants_warn_bytes", limit)
+    assert len(res.history["round"]) == 6
+    captured = [str(w.message) for w in caught
+                if "constants were captured" in str(w.message)]
+    assert not captured, captured
+
+
 def test_make_block_runner_binds_recorder():
     """make_block_runner: the bound runner reproduces run_round_blocks."""
     from repro.core.executor import make_block_runner

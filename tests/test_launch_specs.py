@@ -74,3 +74,36 @@ def test_opts_tag():
     assert dryrun.Opts().tag() == "baseline"
     t = dryrun.Opts(attn_bf16=True, microbatches=4, moe_grouped=True).tag()
     assert "attnbf16" in t and "mb4" in t and "moegrp" in t
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; without it the cache sits
+    at the fixed .jax_cache/ in the checkout. Restores the config, so no
+    test compiles into a cache."""
+    from repro.launch import compile_cache
+
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        got = compile_cache.enable_compile_cache()
+        after = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    if env_dir is None:
+        assert got == after == str(compile_cache.CACHE_DIR)
+        assert compile_cache.CACHE_DIR.name == ".jax_cache"
+        assert (compile_cache.CACHE_DIR.parent / "chip_smoke.py").exists()
+    else:
+        assert got == env_dir and after == before
+
+
+def test_chip_peaks_are_keyed_by_device_kind():
+    from repro.launch import mesh
+
+    assert mesh.chip_peaks(mesh.PRODUCTION_DEVICE_KIND)["flops_bf16"] == 197e12
+    with pytest.raises(ValueError, match="no published peaks"):
+        mesh.chip_peaks("cpu")
