@@ -1,0 +1,8 @@
+"""Device time in the recorder's row and stop test (``cola.record``)
+over the part of the traced window the op line covers, %, mean over
+devices (``_scopes``)."""
+from bench.layer_metrics import _scopes
+
+
+def read(run):
+    return _scopes.device_share(run, _scopes.RECORD)

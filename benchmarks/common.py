@@ -41,12 +41,12 @@ def timeit_rounds(runners, rounds, *, repeats=3, ready=None, label="bench"):
         ready = lambda res: jax.block_until_ready(res.state.x_parts)
     single = callable(runners)
     runs = [runners] if single else list(runners)
-    with obs_trace.span(f"{label}-warmup", runners=len(runs)):
+    with obs_trace.span(f"{label}-warmup"):
         results = [r() for r in runs]
     bests = [0.0] * len(runs)
     for rep in range(repeats):
         for i, r in enumerate(runs):
-            with obs_trace.span(f"{label}-repeat", runner=i, rep=rep):
+            with obs_trace.span(f"{label}-repeat"):
                 t0 = time.perf_counter()
                 res = r()
                 ready(res)

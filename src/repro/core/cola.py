@@ -208,6 +208,10 @@ def _round_body(problem: Problem, part: Partition, cfg: ColaConfig, *,
     dx after the solve (free riders). All elementwise per node, so the
     simulator's (K,) entries and the distributed runtime's node-sharded
     slices produce bitwise-identical rounds.
+
+    The phases run under device scopes (``jax.named_scope``, names in the
+    ops' metadata only): ``cola.mix`` (step 4), ``cola.grad``,
+    ``cola.local_solve`` (step 5) and ``cola.update`` (steps 6-8).
     """
     k = part.num_nodes
     sigma = cfg.resolved_sigma(k)
@@ -251,84 +255,94 @@ def _round_body(problem: Problem, part: Partition, cfg: ColaConfig, *,
         # honest (a two-faced attacker — the stealthiest case for the
         # certificate layer to catch). v_self=None flags the honest fast
         # path, which is then bitwise the unattacked program.
-        if quantized and not lowered_qmix and (cfg.robust is not None or atk):
-            # quantized wire composed with attacks and/or a robust defense
-            # (simulator only — _check_wire_config scopes it to the dense
-            # path, gossip_steps=1, no pipeline): the lie transforms the
-            # fp32 value and is then ENCODED, so only codec payloads ever
-            # cross the narrow wire; each node's own slot (and its EF
-            # residual) tracks the codec view of its HONEST value, making
-            # honest nodes' draws — and a clean defended run — bitwise the
-            # undefended quantized program's.
-            key0 = None if qkey is None else quant.step_key(qkey, 0)
-            _, _, deq_self, ef_new = quant.encode(state.v_stack, cfg.wire,
-                                                  key0, None, state.ef)
-            v_send = _apply_payload_attack(state.v_stack, atk)
-            if v_send is state.v_stack:
-                deq_send, self_stack = deq_self, None
+        with jax.named_scope("cola.mix"):
+            if quantized and not lowered_qmix and (cfg.robust is not None
+                                                   or atk):
+                # quantized wire composed with attacks and/or a robust
+                # defense (simulator only — _check_wire_config scopes it to
+                # the dense path, gossip_steps=1, no pipeline): the lie
+                # transforms the fp32 value and is then ENCODED, so only
+                # codec payloads ever cross the narrow wire; each node's own
+                # slot (and its EF residual) tracks the codec view of its
+                # HONEST value, making honest nodes' draws — and a clean
+                # defended run — bitwise the undefended quantized program's.
+                key0 = None if qkey is None else quant.step_key(qkey, 0)
+                _, _, deq_self, ef_new = quant.encode(
+                    state.v_stack, cfg.wire, key0, None, state.ef)
+                v_send = _apply_payload_attack(state.v_stack, atk)
+                if v_send is state.v_stack:
+                    deq_send, self_stack = deq_self, None
+                else:
+                    p_atk = v_send if state.ef is None else v_send + state.ef
+                    qa, sa = quant.quantize_rows(p_atk, cfg.wire, key0)
+                    deq_send, self_stack = quant.dequantize(qa, sa), deq_self
+                if cfg.robust is not None:
+                    v_half = mixing.robust_mix_steps(
+                        w, deq_send, cfg.robust, trim=cfg.robust_trim,
+                        clip=cfg.robust_clip, steps=cfg.gossip_steps,
+                        self_stack=self_stack)
+                else:
+                    v_half = mixing.mix_power_wire(w, deq_send, self_stack,
+                                                   cfg.gossip_steps)
+            elif quantized:
+                # quantized wire: EF-compensated codec view of every
+                # payload; when pipelining, state.buf holds the step-0
+                # payload encoded at the end of the previous round — the
+                # first ppermutes issue here, BEFORE this round's CD solve
+                # below
+                v_half, ef_new = qmix_fn(w, state.v_stack, state.ef, qkey,
+                                         state.buf)
             else:
-                p_atk = v_send if state.ef is None else v_send + state.ef
-                qa, sa = quant.quantize_rows(p_atk, cfg.wire, key0)
-                deq_send, self_stack = quant.dequantize(qa, sa), deq_self
-            if cfg.robust is not None:
-                v_half = mixing.robust_mix_steps(
-                    w, deq_send, cfg.robust, trim=cfg.robust_trim,
-                    clip=cfg.robust_clip, steps=cfg.gossip_steps,
-                    self_stack=self_stack)
-            else:
-                v_half = mixing.mix_power_wire(w, deq_send, self_stack,
-                                               cfg.gossip_steps)
-        elif quantized:
-            # quantized wire: EF-compensated codec view of every payload;
-            # when pipelining, state.buf holds the step-0 payload encoded
-            # at the end of the previous round — the first ppermutes issue
-            # here, BEFORE this round's CD solve below
-            v_half, ef_new = qmix_fn(w, state.v_stack, state.ef, qkey,
-                                     state.buf)
-        else:
-            v_send = _apply_payload_attack(state.v_stack, atk)
-            v_self = None if v_send is state.v_stack else state.v_stack
-            v_half = mix_fn(w, v_send, v_self)
+                v_send = _apply_payload_attack(state.v_stack, atk)
+                v_self = None if v_send is state.v_stack else state.v_stack
+                v_half = mix_fn(w, v_send, v_self)
 
         # Gradient each node uses for its subproblem.
-        grads = jax.vmap(problem.grad_f)(v_half)
-        if cfg.grad_mode == "mixed":
-            # App. E.1: use the neighborhood-mixed gradient sum_l W_kl grad f(v_l).
-            grads = grad_mix_fn(w, grads)
+        with jax.named_scope("cola.grad"):
+            grads = jax.vmap(problem.grad_f)(v_half)
+            if cfg.grad_mode == "mixed":
+                # App. E.1: use the neighborhood-mixed gradient
+                # sum_l W_kl grad f(v_l).
+                grads = grad_mix_fn(w, grads)
 
-        # Step 5: Theta-approximate local subproblem solve (kappa * n_k CD
-        # steps; per-node budgets model heterogeneous Theta_k, Definition 5).
-        use_gram = (env.gram_parts is not None
-                    and cfg.use_gram(problem.d, part.block,
-                                     env.a_parts.dtype.itemsize))
-        if cfg.cd_mode == "gram" and env.gram_parts is None:
-            raise ValueError(
-                "cd_mode='gram' but the env has no Gram blocks — build it "
-                "with build_env(problem, part, with_gram=True)")
-        dx = cd_solve_all(problem, spec, env.a_parts, state.x_parts, grads,
-                          env.gp_parts, env.masks, cfg.coord_steps(part.block),
-                          step_budgets=budgets,
-                          gram_parts=env.gram_parts if use_gram else None)
-        dx = dx * active[:, None].astype(dx.dtype)
-        if atk is not None and "work" in atk:
-            # free riders: no local progress this round
-            dx = dx * atk["work"][:, None].astype(dx.dtype)
+        with jax.named_scope("cola.local_solve"):
+            # Step 5: Theta-approximate local subproblem solve (kappa * n_k
+            # CD steps; per-node budgets model heterogeneous Theta_k,
+            # Definition 5).
+            use_gram = (env.gram_parts is not None
+                        and cfg.use_gram(problem.d, part.block,
+                                         env.a_parts.dtype.itemsize))
+            if cfg.cd_mode == "gram" and env.gram_parts is None:
+                raise ValueError(
+                    "cd_mode='gram' but the env has no Gram blocks — build "
+                    "it with build_env(problem, part, with_gram=True)")
+            dx = cd_solve_all(problem, spec, env.a_parts, state.x_parts,
+                              grads, env.gp_parts, env.masks,
+                              cfg.coord_steps(part.block),
+                              step_budgets=budgets,
+                              gram_parts=env.gram_parts if use_gram else None)
+            dx = dx * active[:, None].astype(dx.dtype)
+            if atk is not None and "work" in atk:
+                # free riders: no local progress this round
+                dx = dx * atk["work"][:, None].astype(dx.dtype)
 
         # Steps 6-8: local variable + local estimate updates.
-        x_new = state.x_parts + cfg.gamma * dx
-        dv = jnp.einsum("kdn,kn->kd", env.a_parts, dx, precision=MATMUL)
-        v_new = v_half + cfg.gamma * k * dv
-        if not quantized:
-            return ColaState(x_parts=x_new, v_stack=v_new)
-        buf_new = None
-        if cfg.pipeline:
-            # modulo schedule: encode the NEXT round's step-0 payload now,
-            # with the next round's codec key — bitwise what the next round
-            # would have encoded at its top, just issued one round early
-            q, s, _, ef_new = qencode_fn(v_new, ef_new, qkey_next)
-            buf_new = (q, s)
-        return ColaState(x_parts=x_new, v_stack=v_new, ef=ef_new,
-                         buf=buf_new)
+        with jax.named_scope("cola.update"):
+            x_new = state.x_parts + cfg.gamma * dx
+            dv = jnp.einsum("kdn,kn->kd", env.a_parts, dx, precision=MATMUL)
+            v_new = v_half + cfg.gamma * k * dv
+            if not quantized:
+                return ColaState(x_parts=x_new, v_stack=v_new)
+            buf_new = None
+            if cfg.pipeline:
+                # modulo schedule: encode the NEXT round's step-0 payload
+                # now, with the next round's codec key — bitwise what the
+                # next round would have encoded at its top, just issued one
+                # round early
+                q, s, _, ef_new = qencode_fn(v_new, ef_new, qkey_next)
+                buf_new = (q, s)
+            return ColaState(x_parts=x_new, v_stack=v_new, ef=ef_new,
+                             buf=buf_new)
 
     return one_round
 
@@ -416,6 +430,7 @@ def run_cola(problem: Problem, graph: topo.Topology, cfg: ColaConfig,
         rngs identically and produce bitwise-identical states.
       block_size: rounds per dispatch for the block executor.
     """
+    from repro.obs import trace as obs_trace   # obs imports this module
     k = graph.num_nodes
     _check_wire_config(cfg, attacks=attacks, leave_mode=leave_mode)
     part = make_partition(problem.n, k)
@@ -443,23 +458,25 @@ def run_cola(problem: Problem, graph: topo.Topology, cfg: ColaConfig,
                 block_size=block_size)
     # honor cfg.cd_mode: forced "gram" must materialize the blocks even when
     # the heuristic declines, forced "residual" must not pay for them
-    env = build_env(problem, part,
-                    with_gram=cfg.use_gram(problem.d, part.block,
-                                           problem.a.dtype.itemsize))
+    with obs_trace.span("env-build"):
+        env = build_env(problem, part,
+                        with_gram=cfg.use_gram(problem.d, part.block,
+                                               problem.a.dtype.itemsize))
     state = init_state(problem, part)
     base_w = w_override if w_override is not None else topo.metropolis_weights(graph)
-    rec = metrics_lib.make_recorder(recorder, problem, part, env, graph,
-                                    base_w, eps)
     active_schedule = _as_schedule_fn(active_schedule, rounds, k,
                                       "active_schedule")
     budget_schedule = _as_schedule_fn(budget_schedule, rounds, k,
                                       "budget_schedule")
-    if active_schedule is not None or sample is not None:
-        # churn (and client sampling, which is streamed churn): certificates
-        # must judge each record round against the REWEIGHTED exchange
-        # (mask + beta of the active subnetwork), not the static graph
-        # baked at init
-        rec = metrics_lib.dynamize(rec)
+    with obs_trace.span("recorder-setup"):
+        rec = metrics_lib.make_recorder(recorder, problem, part, env, graph,
+                                        base_w, eps)
+        if active_schedule is not None or sample is not None:
+            # churn (and client sampling, which is streamed churn):
+            # certificates must judge each record round against the
+            # REWEIGHTED exchange (mask + beta of the active subnetwork),
+            # not the static graph baked at init
+            rec = metrics_lib.dynamize(rec)
     args = (problem, part, env, state, graph, cfg, rounds, record_every,
             rec, active_schedule, budget_schedule, leave_mode, seed, base_w)
     if executor == "block":
@@ -697,28 +714,34 @@ def _run_cola_block(problem, part, env, state, graph, cfg, rounds,
     """Round-block driver: ``block_size`` rounds per dispatch (see
     ``repro.core.executor``), the Recorder's row computed on device inside
     the scan, certificate-driven early exit handled by the engine."""
+    from repro.obs import trace as obs_trace   # obs imports this module
     dtype = problem.a.dtype
     sample = cfg.participation
-    sched = _materialize_schedule(graph, rounds, active_schedule,
-                                  budget_schedule, leave_mode, seed, base_w,
-                                  dtype)
     atk_info = None
     atk_part = None
+    with obs_trace.span("schedule-build"):
+        sched = _materialize_schedule(graph, rounds, active_schedule,
+                                      budget_schedule, leave_mode, seed,
+                                      base_w, dtype)
+        if attacks is not None:
+            from repro import attack as attack_lib
+            ctx = attack_lib.AttackContext(graph=graph, rounds=rounds,
+                                           k=part.num_nodes, d=problem.d,
+                                           dtype=dtype, seed=seed)
+            if sample is not None:
+                # a participation run streams its schedule, so the attacks
+                # must be generative too: one composed jax part rides the
+                # same stream (W-rewriting / recording scenarios raise here)
+                atk_part, atk_info = attack_lib.streamed_attacks(attacks,
+                                                                 ctx)
+            else:
+                # attacks transform the schedule AFTER churn/budgets
+                # materialize and BEFORE the certificate schedule derives
+                # from it — certificates judge the corrupted exchange,
+                # exactly what ran
+                sched, atk_info = attack_lib.apply_attacks(sched, attacks,
+                                                           ctx)
     if attacks is not None:
-        from repro import attack as attack_lib
-        ctx = attack_lib.AttackContext(graph=graph, rounds=rounds,
-                                       k=part.num_nodes, d=problem.d,
-                                       dtype=dtype, seed=seed)
-        if sample is not None:
-            # a participation run streams its schedule, so the attacks must
-            # be generative too: one composed jax part rides the same
-            # stream (W-rewriting / recording scenarios raise here)
-            atk_part, atk_info = attack_lib.streamed_attacks(attacks, ctx)
-        else:
-            # attacks transform the schedule AFTER churn/budgets materialize
-            # and BEFORE the certificate schedule derives from it —
-            # certificates judge the corrupted exchange, exactly what ran
-            sched, atk_info = attack_lib.apply_attacks(sched, attacks, ctx)
         if "dishonest" in atk_info.entry_names:
             # payload-corrupting attacks: the certificate audits the honest
             # cohort against the ground-truth dishonesty mask the schedule
@@ -817,7 +840,6 @@ def _run_cola_block(problem, part, env, state, graph, cfg, rounds,
         if cfg.telemetry:
             # scope a fresh tracer (+ its cache listener) to this run so the
             # report's span timings cover exactly these block dispatches
-            from repro.obs import trace as obs_trace
             run_tr = stack.enter_context(obs_trace.use(obs_trace.Tracer()))
             stack.enter_context(run_tr.attach())
         res = exec_engine.run_round_blocks(

@@ -383,6 +383,11 @@ def block_program(step_fn: Callable[[Any, Any, Any], tuple[Any, Any]],
     [, t_idx])`` with ``stop_fn``; ``((state, stopped, next, every), ctx,
     sched, t_idx, force)`` with an adaptive ``cadence`` (which needs
     ``ratio_fn``). The state is donated.
+
+    The recorder's row and the stop test run under the device scope
+    ``cola.record``, a streamed schedule under ``cola.schedule``
+    (``jax.named_scope``: names in the ops' metadata, which a device
+    profile carries; the computation is unchanged).
     """
     has_stop = stop_fn is not None
     has_cadence = cadence is not None and lifted_rec is not None
@@ -416,26 +421,28 @@ def block_program(step_fn: Callable[[Any, Any, Any], tuple[Any, Any]],
                 s, stopped, nxt, every = carry
                 sched_t, t, force_t = xs
                 if stream is not None:
-                    sched_t = {**sched_t, **stream(t)}
+                    with jax.named_scope("cola.schedule"):
+                        sched_t = {**sched_t, **stream(t)}
                 s, aux = lax.cond(
                     stopped, lambda ss: skip_step(ss, ctx, sched_t),
                     lambda ss: step_fn(ss, ctx[0], sched_t), s)
-                due = jnp.logical_or(t >= nxt, force_t)
-                do_rec = jnp.logical_and(due, jnp.logical_not(stopped))
-                row = lax.cond(do_rec,
-                               lambda ss: rec_call(ss, sched_t, ctx),
-                               lambda ss: zero_row(ss, sched_t, ctx), s)
-                # geometric back-off while far from the stop threshold,
-                # snap to base inside the near band; the zero row of a
-                # non-record round is discarded by the where() gates
-                far = ratio_fn(row).astype(jnp.float32) > near
-                new_every = jnp.where(
-                    far, jnp.minimum(every * grow, max_e), base)
-                every = jnp.where(do_rec, new_every, every)
-                nxt = jnp.where(do_rec, t + new_every, nxt)
-                if stop_fn is not None:
-                    stop_now = jnp.logical_and(do_rec, stop_fn(row))
-                    stopped = jnp.logical_or(stopped, stop_now)
+                with jax.named_scope("cola.record"):
+                    due = jnp.logical_or(t >= nxt, force_t)
+                    do_rec = jnp.logical_and(due, jnp.logical_not(stopped))
+                    row = lax.cond(do_rec,
+                                   lambda ss: rec_call(ss, sched_t, ctx),
+                                   lambda ss: zero_row(ss, sched_t, ctx), s)
+                    # geometric back-off while far from the stop threshold,
+                    # snap to base inside the near band; the zero row of a
+                    # non-record round is discarded by the where() gates
+                    far = ratio_fn(row).astype(jnp.float32) > near
+                    new_every = jnp.where(
+                        far, jnp.minimum(every * grow, max_e), base)
+                    every = jnp.where(do_rec, new_every, every)
+                    nxt = jnp.where(do_rec, t + new_every, nxt)
+                    if stop_fn is not None:
+                        stop_now = jnp.logical_and(do_rec, stop_fn(row))
+                        stopped = jnp.logical_or(stopped, stop_now)
                 return (s, stopped, nxt, every), (aux, row, do_rec)
             return lax.scan(body, carry0, (sched, t_idx, force))
 
@@ -454,9 +461,10 @@ def block_program(step_fn: Callable[[Any, Any, Any], tuple[Any, Any]],
                     s, aux = step_fn(s, ctx[0], sched_t)
                     if lifted_rec is None:
                         return s, (aux, None)
-                    row = lax.cond(rec_t,
-                                   lambda ss: rec_call(ss, sched_t, ctx),
-                                   lambda ss: zero_row(ss, sched_t, ctx), s)
+                    with jax.named_scope("cola.record"):
+                        row = lax.cond(
+                            rec_t, lambda ss: rec_call(ss, sched_t, ctx),
+                            lambda ss: zero_row(ss, sched_t, ctx), s)
                     return s, (aux, row)
                 return lax.scan(body, st, (sched, rec))
 
@@ -466,13 +474,15 @@ def block_program(step_fn: Callable[[Any, Any, Any], tuple[Any, Any]],
         def run_block_streamed(st, ctx, sched, rec, t_idx):
             def body(s, xs):
                 sched_t, rec_t, t = xs
-                sched_t = {**sched_t, **stream(t)}
+                with jax.named_scope("cola.schedule"):
+                    sched_t = {**sched_t, **stream(t)}
                 s, aux = step_fn(s, ctx[0], sched_t)
                 if lifted_rec is None:
                     return s, (aux, None)
-                row = lax.cond(rec_t,
-                               lambda ss: rec_call(ss, sched_t, ctx),
-                               lambda ss: zero_row(ss, sched_t, ctx), s)
+                with jax.named_scope("cola.record"):
+                    row = lax.cond(rec_t,
+                                   lambda ss: rec_call(ss, sched_t, ctx),
+                                   lambda ss: zero_row(ss, sched_t, ctx), s)
                 return s, (aux, row)
             return lax.scan(body, st, (sched, rec, t_idx))
 
@@ -486,16 +496,18 @@ def block_program(step_fn: Callable[[Any, Any, Any], tuple[Any, Any]],
                 sched_t, rec_t = xs
             else:
                 sched_t, rec_t, t = xs
-                sched_t = {**sched_t, **stream(t)}
+                with jax.named_scope("cola.schedule"):
+                    sched_t = {**sched_t, **stream(t)}
 
             s, aux = lax.cond(
                 stopped, lambda ss: skip_step(ss, ctx, sched_t),
                 lambda ss: step_fn(ss, ctx[0], sched_t), s)
-            do_rec = jnp.logical_and(rec_t, jnp.logical_not(stopped))
-            row = lax.cond(do_rec,
-                           lambda ss: rec_call(ss, sched_t, ctx),
-                           lambda ss: zero_row(ss, sched_t, ctx), s)
-            stop_now = jnp.logical_and(do_rec, stop_fn(row))
+            with jax.named_scope("cola.record"):
+                do_rec = jnp.logical_and(rec_t, jnp.logical_not(stopped))
+                row = lax.cond(do_rec,
+                               lambda ss: rec_call(ss, sched_t, ctx),
+                               lambda ss: zero_row(ss, sched_t, ctx), s)
+                stop_now = jnp.logical_and(do_rec, stop_fn(row))
             return (s, jnp.logical_or(stopped, stop_now)), \
                 (aux, row, do_rec)
         xs = (sched, rec) if stream is None else (sched, rec, t_idx)
@@ -605,16 +617,15 @@ def run_round_blocks(step_fn: Callable[[Any, Any, Any], tuple[Any, Any]],
         cache_key = (cache_key, ("record-shapes",
                                  _record_shape_key(state, schedule)))
 
-    # phase tracing (repro.obs.trace): the active tracer records the driver
-    # build (trace time — runs only on a cache miss/bypass) and every block
-    # dispatch. The first dispatch span absorbs the XLA compile; steady
-    # blocks measure dispatch (+ the per-block stop-flag sync when early
-    # exit is armed). Lazy import: obs.trace imports this module.
+    # phase spans (repro.obs.trace): the driver build (trace time — runs
+    # only on a cache miss/bypass), every block dispatch (the first absorbs
+    # the XLA compile), the per-block stop-flag sync when early exit is
+    # armed, and the history fetch at the end. Lazy import: obs.trace
+    # imports this module.
     from repro.obs import trace as obs_trace
-    tracer = obs_trace.current()
 
     def timed_build():
-        with tracer.span("driver-build", key=cache_key is not None):
+        with obs_trace.span("driver-build"):
             lifted_rec, rec_consts = None, []
             if record_fn is not None:
                 # a cache hit reuses these arrays: the content-addressed key
@@ -653,7 +664,7 @@ def run_round_blocks(step_fn: Callable[[Any, Any, Any], tuple[Any, Any]],
             span_name = ("block-first-dispatch" if n_dispatch == 0
                          else "block-dispatch")
             n_dispatch += 1
-            with tracer.span(span_name, start=start, rounds=stop - start):
+            with obs_trace.span(span_name):
                 sched_b = jax.tree.map(lambda x: jnp.asarray(x[start:stop]),
                                        schedule)
                 if has_cadence:
@@ -684,34 +695,38 @@ def run_round_blocks(step_fn: Callable[[Any, Any, Any], tuple[Any, Any]],
                     auxes.append(aux_b)
                 start = stop
                 executed = stop
-                # the host-side short-circuit: one scalar sync per block,
-                # only when early exit is armed
-                if has_stop and bool(stop_flag):
-                    stopped_early = True
+            # the host-side short-circuit: one scalar sync per block, only
+            # when early exit is armed
+            if has_stop:
+                with obs_trace.span("stop-sync"):
+                    stopped_early = bool(stop_flag)
+                if stopped_early:
                     break
 
     metrics = rounds = None
     stop_round = None
-    if record_fn is not None:
-        if (has_stop or has_cadence) and valids:
-            valid = np.concatenate([np.asarray(v) for v in valids], axis=0)
-        else:
-            valid = rec_all[:executed]
-        if rows:
-            metrics = np.concatenate([np.asarray(r) for r in rows],
-                                     axis=0)[valid]
-            rounds = np.nonzero(valid)[0]
-        else:  # T == 0: empty history, same as the loop drivers
-            row_sd = jax.eval_shape(lifted_rec, rec_consts, state,
-                                    _round_slice_shapes(schedule, stream))
-            metrics = np.zeros((0,) + row_sd.shape, row_sd.dtype)
-            rounds = np.zeros((0,), dtype=np.int64)
-        if stopped_early and rounds.size:
-            stop_round = int(rounds[-1])
     aux = None
-    if auxes:
-        aux = jax.tree.map(lambda *xs: np.concatenate(
-            [np.asarray(x) for x in xs], axis=0), *auxes)
+    with obs_trace.span("history-fetch"):
+        if record_fn is not None:
+            if (has_stop or has_cadence) and valids:
+                valid = np.concatenate([np.asarray(v) for v in valids],
+                                       axis=0)
+            else:
+                valid = rec_all[:executed]
+            if rows:
+                metrics = np.concatenate([np.asarray(r) for r in rows],
+                                         axis=0)[valid]
+                rounds = np.nonzero(valid)[0]
+            else:  # T == 0: empty history, same as the loop drivers
+                row_sd = jax.eval_shape(lifted_rec, rec_consts, state,
+                                        _round_slice_shapes(schedule, stream))
+                metrics = np.zeros((0,) + row_sd.shape, row_sd.dtype)
+                rounds = np.zeros((0,), dtype=np.int64)
+            if stopped_early and rounds.size:
+                stop_round = int(rounds[-1])
+        if auxes:
+            aux = jax.tree.map(lambda *xs: np.concatenate(
+                [np.asarray(x) for x in xs], axis=0), *auxes)
     return BlockRunResult(state=state, metrics=metrics, aux=aux,
                           rounds=rounds, stop_round=stop_round)
 

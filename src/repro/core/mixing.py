@@ -169,8 +169,10 @@ def ring_mix_ppermute(v_local: jax.Array, axis_name: str, weights: jax.Array,
     for off in range(1, conn + 1):
         # receive from left neighbor at distance `off`
         perm_l = [((i + off) % k, i) for i in range(k)]
-        from_right = lax.ppermute(v_local, axis_name, [(i, (i + off) % k) for i in range(k)])
-        from_left = lax.ppermute(v_local, axis_name, perm_l)
+        with jax.named_scope("cola.exchange"):
+            from_right = lax.ppermute(
+                v_local, axis_name, [(i, (i + off) % k) for i in range(k)])
+            from_left = lax.ppermute(v_local, axis_name, perm_l)
         out = out + weights[conn + off] * from_left + weights[conn - off] * from_right
     return out
 

@@ -130,7 +130,8 @@ def _dist_mixers(axis: str, local_nodes: int, conn: int, comm: str,
         def steps_mix(w, stack, steps):
             if steps <= 0:
                 return stack
-            full = lax.all_gather(stack, axis, tiled=True)      # (K, d)
+            with jax.named_scope("cola.exchange"):
+                full = lax.all_gather(stack, axis, tiled=True)  # (K, d)
             mixed = mixing.mix_power(w, full, steps)
             i = lax.axis_index(axis)
             return lax.dynamic_slice_in_dim(mixed, i * local_nodes,
@@ -182,8 +183,9 @@ def _dist_mixers(axis: str, local_nodes: int, conn: int, comm: str,
             def mix_fn(w, v_send, v_self):
                 if v_self is None:
                     return steps_mix(w, v_send, gossip_steps)
-                full = lax.all_gather(v_send, axis, tiled=True)
-                full_self = lax.all_gather(v_self, axis, tiled=True)
+                with jax.named_scope("cola.exchange"):
+                    full = lax.all_gather(v_send, axis, tiled=True)
+                    full_self = lax.all_gather(v_self, axis, tiled=True)
                 mixed = mixing.mix_power_wire(w, full, full_self,
                                               gossip_steps)
                 i = lax.axis_index(axis)
@@ -214,9 +216,10 @@ def _dist_mixers(axis: str, local_nodes: int, conn: int, comm: str,
         def mix_fn(w, v_send, v_self):
             if gossip_steps <= 0:
                 return v_send
-            full = lax.all_gather(v_send, axis, tiled=True)   # (K, d)
-            full_self = (None if v_self is None
-                         else lax.all_gather(v_self, axis, tiled=True))
+            with jax.named_scope("cola.exchange"):
+                full = lax.all_gather(v_send, axis, tiled=True)  # (K, d)
+                full_self = (None if v_self is None
+                             else lax.all_gather(v_self, axis, tiled=True))
             mixed = mixing.robust_mix_steps(w, full, robust,
                                             trim=robust_trim,
                                             clip=robust_clip,
@@ -334,15 +337,16 @@ def _dist_qmixers(axis: str, local_nodes: int, comm: str, cfg: ColaConfig,
                 # fp32 sidecar — quantize-then-gather, never the reverse
                 # (gathered as raw bytes so no backend upcasts float8,
                 # see topo_lowering.ppermute_wire)
-                if q.dtype.itemsize == 1 and \
-                        jnp.issubdtype(q.dtype, jnp.floating):
-                    qf = lax.bitcast_convert_type(
-                        lax.all_gather(
-                            lax.bitcast_convert_type(q, jnp.uint8),
-                            axis, tiled=True), q.dtype)
-                else:
-                    qf = lax.all_gather(q, axis, tiled=True)
-                sf = lax.all_gather(sc, axis, tiled=True)
+                with jax.named_scope("cola.exchange"):
+                    if q.dtype.itemsize == 1 and \
+                            jnp.issubdtype(q.dtype, jnp.floating):
+                        qf = lax.bitcast_convert_type(
+                            lax.all_gather(
+                                lax.bitcast_convert_type(q, jnp.uint8),
+                                axis, tiled=True), q.dtype)
+                    else:
+                        qf = lax.all_gather(q, axis, tiled=True)
+                    sf = lax.all_gather(sc, axis, tiled=True)
                 deq_full = quant.dequantize(qf, sf)
                 if cfg.robust is not None:
                     # composed oracle: the gate judges the dequantized
@@ -463,14 +467,16 @@ def _certificate_dist_record(rec, mesh, axis: str, local_nodes: int,
             g = grads[0]
             nsum = g
             for off in range(1, conn + 1):
-                fwd = lax.ppermute(g, axis,
-                                   [(i, (i + off) % k) for i in range(k)])
-                bwd = lax.ppermute(g, axis,
-                                   [((i + off) % k, i) for i in range(k)])
+                with jax.named_scope("cola.exchange"):
+                    fwd = lax.ppermute(
+                        g, axis, [(i, (i + off) % k) for i in range(k)])
+                    bwd = lax.ppermute(
+                        g, axis, [((i + off) % k, i) for i in range(k)])
                 nsum = nsum + fwd + bwd
             neigh_mean = (nsum / (2 * conn + 1))[None]       # (1, d)
         else:
-            full = lax.all_gather(grads, axis, tiled=True)   # (K, d)
+            with jax.named_scope("cola.exchange"):
+                full = lax.all_gather(grads, axis, tiled=True)  # (K, d)
             neigh_mean = neighborhood_mean(full, nm_l)       # (ln, d)
         # condition (9) uses only this device's blocks — swap the local
         # slices in so the vmapped node math runs on (ln, ...) operands
@@ -631,6 +637,7 @@ def run_dist_cola(problem: Problem, graph: topo.Topology, cfg: ColaConfig,
     Returns ``RunResult(state, history)`` with the fully-stacked (K, ...)
     state, like the simulator.
     """
+    from repro.obs import trace as obs_trace   # obs imports core.cola
     if wire is not None:
         cfg = dataclasses.replace(cfg, wire=wire)
     _check_wire_config(cfg, attacks=attacks, leave_mode=leave_mode,
@@ -695,33 +702,37 @@ def run_dist_cola(problem: Problem, graph: topo.Topology, cfg: ColaConfig,
                 else topo_plan.compile_block_plan(support, m))
 
     part = make_partition(problem.n, k)
-    env = build_env(problem, part,
-                    with_gram=cfg.use_gram(problem.d, part.block,
-                                           problem.a.dtype.itemsize))
+    with obs_trace.span("env-build"):
+        env = build_env(problem, part,
+                        with_gram=cfg.use_gram(problem.d, part.block,
+                                               problem.a.dtype.itemsize))
     state = init_state(problem, part)
     dtype = problem.a.dtype
-    sched = _materialize_schedule(graph, rounds, active_schedule,
-                                  budget_schedule, leave_mode, seed, base_w,
-                                  dtype)
-    if quantized:
-        # the SAME per-round codec key stack both simulator drivers slice —
-        # the stochastic-rounding draws are a function of (seed, round,
-        # step, color, node), never of the mesh layout
-        qkeys = np.asarray(quant.round_keys(seed, rounds + 1))
-        sched["qkey"] = qkeys[:rounds]
-        if cfg.pipeline:
-            sched["qkey_next"] = qkeys[1:]
-        state = _arm_wire_state(state, cfg, qkeys[0])
     atk_info = None
+    with obs_trace.span("schedule-build"):
+        sched = _materialize_schedule(graph, rounds, active_schedule,
+                                      budget_schedule, leave_mode, seed,
+                                      base_w, dtype)
+        if quantized:
+            # the SAME per-round codec key stack both simulator drivers
+            # slice — the stochastic-rounding draws are a function of
+            # (seed, round, step, color, node), never of the mesh layout
+            qkeys = np.asarray(quant.round_keys(seed, rounds + 1))
+            sched["qkey"] = qkeys[:rounds]
+            if cfg.pipeline:
+                sched["qkey_next"] = qkeys[1:]
+            state = _arm_wire_state(state, cfg, qkeys[0])
+        if attacks is not None:
+            from repro import attack as attack_lib
+            # same transform order as the simulator: churn/budgets
+            # materialize, attacks corrupt, then the certificate/plan
+            # schedules derive from the corrupted exchange
+            sched, atk_info = attack_lib.apply_attacks(
+                sched, attacks,
+                attack_lib.AttackContext(graph=graph, rounds=rounds, k=k,
+                                         d=problem.d, dtype=dtype,
+                                         seed=seed))
     if attacks is not None:
-        from repro import attack as attack_lib
-        # same transform order as the simulator: churn/budgets materialize,
-        # attacks corrupt, then the certificate/plan schedules derive from
-        # the corrupted exchange
-        sched, atk_info = attack_lib.apply_attacks(
-            sched, attacks,
-            attack_lib.AttackContext(graph=graph, rounds=rounds, k=k,
-                                     d=problem.d, dtype=dtype, seed=seed))
         if atk_info.tap_nodes:
             raise ValueError(
                 "Eavesdropper taps are simulator-only (per-round payload "
@@ -731,14 +742,16 @@ def run_dist_cola(problem: Problem, graph: topo.Topology, cfg: ColaConfig,
     has_budget = "budgets" in sched
     has_reset = "leavers" in sched
 
-    rec = metrics_lib.make_recorder(recorder, problem, part, env, graph,
-                                    base_w, eps)
-    if active_schedule is not None:
-        rec = metrics_lib.dynamize(rec)  # churn-aware certificate inputs
-    if "dishonest" in atk_names:
-        # payload-corrupting attacks: certificates audit the honest cohort
-        # against the schedule's ground-truth mask (metrics.attackify)
-        rec = metrics_lib.attackify(rec)
+    with obs_trace.span("recorder-setup"):
+        rec = metrics_lib.make_recorder(recorder, problem, part, env, graph,
+                                        base_w, eps)
+        if active_schedule is not None:
+            rec = metrics_lib.dynamize(rec)  # churn-aware certificate inputs
+        if "dishonest" in atk_names:
+            # payload-corrupting attacks: certificates audit the honest
+            # cohort against the schedule's ground-truth mask
+            # (metrics.attackify)
+            rec = metrics_lib.attackify(rec)
 
     # lay the node axis of state + env over the mesh axis up front so the
     # donated buffers never migrate between blocks
@@ -886,7 +899,6 @@ def run_dist_cola(problem: Problem, graph: topo.Topology, cfg: ColaConfig,
     with contextlib.ExitStack() as stack:
         run_tr = None
         if cfg.telemetry:
-            from repro.obs import trace as obs_trace
             run_tr = stack.enter_context(obs_trace.use(obs_trace.Tracer()))
             stack.enter_context(run_tr.attach())
         res = exec_engine.run_round_blocks(

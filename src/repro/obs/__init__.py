@@ -11,10 +11,14 @@ program, bitwise):
   (``repro.core.mixing.gate_flags`` — XLA CSEs the recomputed gate against
   the defended mix, so the counter is free). Totals land in every driver's
   ``history["telemetry"]``.
-* **Host tracing** (``obs.trace``): ``span()`` phase timers (driver build,
-  block dispatches, bench repeats) with a ``jax.profiler`` annotation
-  bridge, driver-cache hit/miss events via ``executor.cache_listener``, and
-  Chrome-trace JSON export.
+* **Host tracing** (``obs.trace``): ``span()`` names a host phase (env
+  build, recorder set-up, schedule build, driver build, block dispatches,
+  stop-flag syncs, history fetch, bench repeats) as a ``jax.profiler``
+  annotation, the same timeline as a device profile; a scoped ``Tracer``
+  also sums span timings and driver-cache hit/miss events
+  (``executor.cache_listener``) for the run report. The device side of a
+  round carries ``jax.named_scope`` names (``cola.mix``,
+  ``cola.local_solve``, ``cola.update``, ``cola.record``, ...).
 * **Run registry** (``obs.report``): telemetry runs append a ``RunReport``
   JSONL line under ``.repro_runs/`` (env ``REPRO_RUNS_DIR`` overrides);
   ``python -m repro.obs list|show|diff|timeline`` queries it.

@@ -129,7 +129,8 @@ def make_update(cfg, k: int, inc: dict):
     ``topo.plan.w_from_coefficients_device``, before the gate recompute;
     silently skipping would report zero rejections for a defended run).
     ``obs_row`` is the f32 (3,) per-round series row
-    ``[saturation, ef_norm, gate_total]``.
+    ``[saturation, ef_norm, gate_total]``. The update runs under the device
+    scope ``obs.counters``.
     """
     quantized = quant.is_quantized(cfg.wire)
     b_inc = jnp.float32(inc["bytes_per_round"])
@@ -198,7 +199,11 @@ def make_update(cfg, k: int, inc: dict):
                        gate=c.gate + gate_t)
         return new, obs_row
 
-    return update
+    def scoped(before, after, s_t, atk, w):
+        with jax.named_scope("obs.counters"):
+            return update(before, after, s_t, atk, w)
+
+    return scoped
 
 
 def summarize(counters: Counters, inc: dict | None = None, *,

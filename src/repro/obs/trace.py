@@ -1,63 +1,52 @@
-"""Host-side phase tracing: spans, driver-cache events, Chrome export.
+"""Host-side phase tracing: named spans and driver-cache events.
 
-A ``Tracer`` records named wall-clock spans (driver build, per-block
-dispatch, bench repeats) plus ``executor.cached_driver`` hit/miss events,
-and exports the whole timeline as Chrome-trace JSON (``chrome://tracing``
-/ Perfetto). Every span also opens a ``jax.profiler.TraceAnnotation`` so
-the same names show up inside a device profile when one is being taken.
+Every ``span()`` opens a ``jax.profiler.TraceAnnotation``, so the name
+shows up on the host timeline of a device profile when one is being taken
+(and costs about a microsecond when none is). The profiler trace is the one
+timeline: it already shares the host clock with the device's events.
 
-A module-level default tracer is always installed — ``span()`` costs two
-``perf_counter`` calls and a deque append, so instrumented code paths
-(executor block dispatches, bench loops) call it unconditionally. Scoped
-collection swaps in a fresh tracer::
+Aggregated timings are opt-in. A ``Tracer`` installed with ``use()``
+also sums each span's count and seconds for the scope, plus
+``executor.cached_driver`` hit/miss events while ``attach()`` is active;
+``summary()`` is the compact form a ``RunReport`` stores::
 
     with obs.trace.use(obs.trace.Tracer()) as tr, tr.attach():
         run()
-    tr.export("trace.json")
+    tr.summary()
+
+Outside any ``use()`` a span records nothing on the host.
 """
 from __future__ import annotations
 
-import collections
 import contextlib
-import json
 import time
-from typing import Any
+
+from jax.profiler import TraceAnnotation
 
 from repro.core import executor
 
-#: default tracer keeps a bounded window so long sessions don't grow it
-_DEFAULT_MAXLEN = 4096
-
 
 class Tracer:
-    """Collects spans + driver-cache events relative to its creation."""
+    """Span timings and driver-cache events of one scope, summed by name."""
 
-    def __init__(self, name: str = "repro", maxlen: int | None = None):
+    def __init__(self, name: str = "repro"):
         self.name = name
-        self.spans: collections.deque = collections.deque(maxlen=maxlen)
-        self.cache_events: collections.deque = collections.deque(
-            maxlen=maxlen)
-        self._t0 = time.perf_counter()
+        self._spans: dict = {}     # name -> [count, total seconds]
+        self._cache = {"hits": 0, "misses": 0, "bypass": 0}
 
     @contextlib.contextmanager
-    def span(self, name: str, **meta: Any):
-        try:
-            from jax.profiler import TraceAnnotation
-            ann = TraceAnnotation(name)
-        except Exception:  # profiler unavailable: host timing still works
-            ann = contextlib.nullcontext()
+    def span(self, name: str):
         t0 = time.perf_counter()
         try:
-            with ann:
+            with TraceAnnotation(name):
                 yield
         finally:
-            self.spans.append({"name": name, "t0": t0 - self._t0,
-                               "dur": time.perf_counter() - t0,
-                               "meta": meta})
+            ent = self._spans.setdefault(name, [0, 0.0])
+            ent[0] += 1
+            ent[1] += time.perf_counter() - t0
 
     def _on_cache(self, key, kind: str) -> None:
-        self.cache_events.append({"t": time.perf_counter() - self._t0,
-                                  "kind": kind, "key": repr(key)})
+        self._cache[kind] = self._cache.get(kind, 0) + 1
 
     @contextlib.contextmanager
     def attach(self):
@@ -68,49 +57,22 @@ class Tracer:
             yield self
 
     def cache_stats(self) -> dict:
-        out = {"hits": 0, "misses": 0, "bypass": 0}
-        for ev in self.cache_events:
-            out[ev["kind"]] = out.get(ev["kind"], 0) + 1
-        return out
+        return dict(self._cache)
 
     def summary(self) -> dict:
         """Span timings aggregated by name (count + total seconds) — the
         compact form a RunReport stores."""
-        agg: dict = {}
-        for s in self.spans:
-            ent = agg.setdefault(s["name"], {"count": 0, "total_s": 0.0})
-            ent["count"] += 1
-            ent["total_s"] += s["dur"]
-        for ent in agg.values():
-            ent["total_s"] = round(ent["total_s"], 6)
-        return {"spans": agg, "cache": self.cache_stats()}
-
-    def chrome_trace(self) -> dict:
-        """The timeline as Chrome trace-event JSON."""
-        evs = []
-        for s in self.spans:
-            evs.append({"name": s["name"], "ph": "X", "pid": 1, "tid": 1,
-                        "ts": s["t0"] * 1e6, "dur": s["dur"] * 1e6,
-                        "args": {str(k): str(v)
-                                 for k, v in s["meta"].items()}})
-        for ev in self.cache_events:
-            evs.append({"name": f"driver-cache {ev['kind']}", "ph": "i",
-                        "pid": 1, "tid": 2, "ts": ev["t"] * 1e6, "s": "t",
-                        "args": {"key": ev["key"]}})
-        return {"traceEvents": evs, "displayTimeUnit": "ms"}
-
-    def export(self, path: str) -> str:
-        with open(path, "w") as f:
-            json.dump(self.chrome_trace(), f)
-        return path
+        spans = {name: {"count": count, "total_s": round(total, 6)}
+                 for name, (count, total) in self._spans.items()}
+        return {"spans": spans, "cache": self.cache_stats()}
 
 
-_STACK: list = [Tracer(maxlen=_DEFAULT_MAXLEN)]
+_STACK: list = []
 
 
-def current() -> Tracer:
-    """The active tracer (innermost ``use()`` scope, else the default)."""
-    return _STACK[-1]
+def current() -> Tracer | None:
+    """The innermost ``use()`` scope's tracer, or None outside any."""
+    return _STACK[-1] if _STACK else None
 
 
 @contextlib.contextmanager
@@ -123,20 +85,8 @@ def use(tracer: Tracer):
         _STACK.remove(tracer)
 
 
-def span(name: str, **meta: Any):
-    """Record a span on the ACTIVE tracer: ``with obs.trace.span("x"): ...``"""
-    return current().span(name, **meta)
-
-
-@contextlib.contextmanager
-def jax_profile(logdir: str):
-    """Bridge to the full ``jax.profiler`` device trace: profiles the scope
-    into ``logdir`` (TensorBoard/XProf format); span annotations recorded
-    inside the scope appear as named host regions in that profile."""
-    import jax
-
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
+def span(name: str):
+    """``with obs.trace.span("x"): ...`` — a profiler annotation, timed on
+    the active tracer when a ``use()`` scope is open."""
+    tracer = current()
+    return TraceAnnotation(name) if tracer is None else tracer.span(name)

@@ -14,6 +14,11 @@ round/record programs when ``comm="plan"``. Two layouts:
   (``block_mix_step``). Intra-block edges ride the dot as local terms —
   zero communication.
 
+Every exchange (the permutes, and the assembly of a block's neighborhood
+buffer) runs under the device scope ``cola.exchange``
+(``jax.named_scope``), nested in the round's ``cola.mix`` or the
+recorder's ``cola.record``.
+
 Nothing here gathers a (K, ...) stack collectively — the whole point of
 the compiler is that the lowered HLO contains collective-permutes of block-
 sized payloads only, which the dist tests assert via ``launch.hlo_analysis``.
@@ -56,7 +61,8 @@ def plan_mix_step(v_local, axis_name: str, plan: CommPlan, diag, coefs):
     for c, perm in enumerate(plan.perms):
         # a matching's swap involution: unmatched devices receive zeros,
         # and their coefficient is 0 by construction — no conditional needed
-        recv = lax.ppermute(v_local, axis_name, list(perm))
+        with jax.named_scope("cola.exchange"):
+            recv = lax.ppermute(v_local, axis_name, list(perm))
         out = out + coefs[c] * recv
     return out
 
@@ -101,15 +107,16 @@ def block_gather_neighbors(x_block, axis_name: str, plan: BlockPlan):
     flat = x_block.reshape(ln, -1)
     i = lax.axis_index(axis_name)
     partners = jnp.asarray(plan.block.partner_arrays())     # (C, M) static
-    buf = jnp.zeros((plan.num_nodes, flat.shape[1]), flat.dtype)
-    buf = lax.dynamic_update_slice_in_dim(buf, flat, i * ln, 0)
-    for c, perm in enumerate(plan.block.perms):
-        recv = lax.ppermute(flat, axis_name, list(perm))
-        src = partners[c, i]
-        # unmatched devices receive ppermute zero-fill and src == i: write
-        # the own block back instead of clobbering it with zeros
-        buf = lax.dynamic_update_slice_in_dim(
-            buf, jnp.where(src != i, recv, flat), src * ln, 0)
+    with jax.named_scope("cola.exchange"):
+        buf = jnp.zeros((plan.num_nodes, flat.shape[1]), flat.dtype)
+        buf = lax.dynamic_update_slice_in_dim(buf, flat, i * ln, 0)
+        for c, perm in enumerate(plan.block.perms):
+            recv = lax.ppermute(flat, axis_name, list(perm))
+            src = partners[c, i]
+            # unmatched devices receive ppermute zero-fill and src == i:
+            # write the own block back instead of clobbering it with zeros
+            buf = lax.dynamic_update_slice_in_dim(
+                buf, jnp.where(src != i, recv, flat), src * ln, 0)
     return buf
 
 
@@ -213,8 +220,9 @@ def plan_qmix_steps(v_local, ef_local, axis_name: str, plan: CommPlan,
                 ef = (p - deq).reshape(ef.shape)
         acc = diag * deq
         for c, perm in enumerate(plan.perms):
-            rq = ppermute_wire(q, axis_name, list(perm))
-            rs = lax.ppermute(sc, axis_name, list(perm))
+            with jax.named_scope("cola.exchange"):
+                rq = ppermute_wire(q, axis_name, list(perm))
+                rs = lax.ppermute(sc, axis_name, list(perm))
             acc = acc + coefs[c] * quant.dequantize(rq, rs)
         out = acc.reshape(out.shape)
     return out, ef
@@ -231,15 +239,16 @@ def block_gather_neighbors_q(q, scale, deq, axis_name: str, plan: BlockPlan):
     ln = plan.local_nodes
     i = lax.axis_index(axis_name)
     partners = jnp.asarray(plan.block.partner_arrays())     # (C, M) static
-    buf = jnp.zeros((plan.num_nodes, deq.shape[1]), deq.dtype)
-    buf = lax.dynamic_update_slice_in_dim(buf, deq, i * ln, 0)
-    for c, perm in enumerate(plan.block.perms):
-        rq = ppermute_wire(q, axis_name, list(perm))
-        rs = lax.ppermute(scale, axis_name, list(perm))
-        recv = quant.dequantize(rq, rs)
-        src = partners[c, i]
-        buf = lax.dynamic_update_slice_in_dim(
-            buf, jnp.where(src != i, recv, deq), src * ln, 0)
+    with jax.named_scope("cola.exchange"):
+        buf = jnp.zeros((plan.num_nodes, deq.shape[1]), deq.dtype)
+        buf = lax.dynamic_update_slice_in_dim(buf, deq, i * ln, 0)
+        for c, perm in enumerate(plan.block.perms):
+            rq = ppermute_wire(q, axis_name, list(perm))
+            rs = lax.ppermute(scale, axis_name, list(perm))
+            recv = quant.dequantize(rq, rs)
+            src = partners[c, i]
+            buf = lax.dynamic_update_slice_in_dim(
+                buf, jnp.where(src != i, recv, deq), src * ln, 0)
     return buf
 
 
@@ -403,7 +412,8 @@ def plan_neighborhood_stats(g_local, axis_name: str, plan: CommPlan,
     partners = jnp.asarray(plan.partner_arrays())          # (C, K) static
     nsum = mask_row[i] * g_local                            # self (mask=1)
     for c, perm in enumerate(plan.perms):
-        recv = lax.ppermute(g_local, axis_name, list(perm))
+        with jax.named_scope("cola.exchange"):
+            recv = lax.ppermute(g_local, axis_name, list(perm))
         nsum = nsum + mask_row[partners[c, i]] * recv
     return nsum, jnp.sum(mask_row)
 

@@ -10,6 +10,7 @@ counter equals the contract budget exactly and gate rejections land only on
 pins the off-twin to today's histories.
 """
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -21,9 +22,10 @@ import jax.numpy as jnp
 
 from repro import attack, topo as topo_programs
 from repro.core import executor as exec_engine, problems
+from repro.core import schedule as schedule_lib, topology
 from repro.core.cola import ColaConfig, run_cola
 from repro.data import synthetic
-from repro.obs import report as obs_report
+from repro.obs import report as obs_report, trace as obs_trace
 from repro.obs.cli import sparkline
 
 ROUNDS = 10
@@ -212,6 +214,128 @@ def test_cache_listener_nesting():
     assert outer == ["hits", "misses"]
     exec_engine.cached_driver(("obs-test", 1), lambda: (lambda: None))
     assert outer == ["hits", "misses"]  # both scopes closed: no leak
+
+
+# --- named phases: device scopes in the programs, host spans in the drivers
+
+ROUND_SCOPES = {"cola.mix", "cola.grad", "cola.local_solve", "cola.update"}
+
+
+@pytest.fixture(scope="module")
+def box_lasso():
+    """A lasso with a box, so the Prop.-1 certificate can stop it."""
+    x, y, _ = synthetic.regression(48, 16, seed=2, sparsity_solution=0.2)
+    return problems.lasso(jnp.asarray(x), jnp.asarray(y), 5e-2, box=5.0)
+
+
+def _first_block_program(monkeypatch, run, lowered=False):
+    """HLO of the first block program ``run()`` dispatches: compiled, or as
+    lowered (the op names it hands to the compiler)."""
+    texts = []
+    real = exec_engine.block_program
+
+    def spy(*args, **kwargs):
+        program = real(*args, **kwargs)
+
+        def dispatch(*xs):
+            if not texts:
+                low = program.lower(*xs)
+                texts.append(low.as_text(debug_info=True) if lowered
+                             else low.compile().as_text())
+            return program(*xs)
+        return dispatch
+
+    monkeypatch.setattr(exec_engine, "block_program", spy)
+    exec_engine.clear_driver_cache()
+    run()
+    return texts[0]
+
+
+def _op_names(hlo: str) -> set:
+    """HLO op_name metadata, or the named locations of a lowered module
+    (a file location is followed by its line)."""
+    return set(re.findall(r'(?:op_name=|loc\()"([^"]*)"(?!:)', hlo))
+
+
+@pytest.mark.parametrize("program", ["gap", "certificate", "plan"])
+def test_round_scopes_in_compiled_programs(program, prob, graph, box_lasso,
+                                           monkeypatch):
+    """Every phase scope lands in the op_name metadata of the programs
+    that run it: the gap-recorder block, a certified streamed block with
+    counters on, and the plan round on a one-device mesh, where
+    ``cola.exchange`` nests in ``cola.mix``. On one device the exchange has
+    nothing to send and XLA folds it away, so that case reads the names
+    the lowered program hands to the compiler."""
+    import jax
+
+    if program == "gap":
+        from repro.analysis import drivers
+        hlo = drivers.sim_block_compiled(prob, graph, ColaConfig(kappa=1.0),
+                                         4, device=jax.devices()[0]
+                                         ).as_text()
+        want = ROUND_SCOPES | {"cola.record"}
+    elif program == "certificate":
+        cfg = ColaConfig(kappa=1.0, telemetry=True,
+                         participation=schedule_lib.SampleConfig(
+                             k_active=4, mode="dense"))
+        hlo = _first_block_program(monkeypatch, lambda: run_cola(
+            box_lasso, topology.complete(8), cfg, 16,
+            recorder="certificate", eps=1.0, record_every=4, block_size=8))
+        want = ROUND_SCOPES | {"cola.record", "cola.schedule",
+                               "obs.counters"}
+    else:
+        from repro.dist.runtime import run_dist_cola
+        mesh = jax.make_mesh((1,), ("data",))
+        hlo = _first_block_program(monkeypatch, lambda: run_dist_cola(
+            prob, graph, ColaConfig(kappa=1.0), mesh, 4, comm="plan"),
+            lowered=True)
+        want = ROUND_SCOPES | {"cola.record", "cola.exchange"}
+    paths = [name.split("/") for name in _op_names(hlo)]
+    scopes = {c for path in paths for c in path
+              if c.startswith(("cola.", "obs."))}
+    assert scopes == want
+    if program == "plan":
+        assert any(path.index("cola.mix") < path.index("cola.exchange")
+                   for path in paths if {"cola.mix", "cola.exchange"}
+                   <= set(path))
+
+
+def test_run_cola_spans(box_lasso):
+    """A certified run opens each set-up span once, one stop-flag sync per
+    dispatched block and one history fetch."""
+    rounds, block = 400, 8
+    with obs_trace.use(obs_trace.Tracer()) as tr:
+        res = run_cola(box_lasso, topology.ring(8), ColaConfig(kappa=1.0),
+                       rounds, recorder="certificate", eps=1.0,
+                       record_every=4, block_size=block)
+    spans = tr.summary()["spans"]
+    for name in ("env-build", "recorder-setup", "schedule-build",
+                 "history-fetch"):
+        assert spans[name]["count"] == 1, name
+    blocks = sum(spans[name]["count"] for name in
+                 ("block-first-dispatch", "block-dispatch") if name in spans)
+    assert spans["stop-sync"]["count"] == blocks
+    # the certificate stopped the run before its budget
+    assert blocks < rounds // block
+    assert obs_trace.current() is None
+
+
+def test_bare_span_records_nothing():
+    """Outside any use() a span is a profiler annotation only: no tracer
+    holds it."""
+    tracer = obs_trace.Tracer()
+    assert obs_trace.current() is None
+    with obs_trace.span("outside"):
+        pass
+    with obs_trace.use(tracer):
+        assert obs_trace.current() is tracer
+        with obs_trace.span("inside"):
+            pass
+    with obs_trace.span("after"):
+        pass
+    assert obs_trace.current() is None
+    assert tracer.summary()["spans"].keys() == {"inside"}
+    assert tracer.summary()["spans"]["inside"]["count"] == 1
 
 
 def test_telemetry_carry_pass():
