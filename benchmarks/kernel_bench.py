@@ -4,8 +4,9 @@
 
 Every row names the backend it ran on and the kernels' mode: ``interpret``
 off a TPU, where the Pallas interpreter executes the kernel body and the
-timings only check the plumbing, and ``mosaic`` on a TPU. There the cd_glm
-rows raise the TPU compiler's refusal (see ``repro.kernels.cd_glm``)."""
+timings only check the plumbing, and ``mosaic`` on a TPU. There the
+residual cd_glm row raises the TPU compiler's refusal (see
+``repro.kernels.cd_glm``)."""
 from __future__ import annotations
 
 import time
@@ -17,8 +18,11 @@ from benchmarks.common import csv_row
 from repro.core import problems
 from repro.core.cola import build_env
 from repro.core.partition import make_partition
-from repro.core.subproblem import SubproblemSpec, block_gram, cd_solve_all
+from repro.core.precision import MATMUL
+from repro.core.subproblem import (SubproblemSpec, block_gram, cd_solve_all,
+                                   cd_solve_gram)
 from repro.data import synthetic
+from repro.kernels import cd_glm
 from repro.kernels.ops import (cd_solve_pallas, flash_attention_ops,
                                interpret_mode)
 from repro.launch.compile_cache import enable_compile_cache
@@ -65,10 +69,14 @@ def run(fast: bool = True):
     # Gram-cached CD: O(n_k) per coordinate step vs the residual path's O(d)
     gram = env.gram_parts if env.gram_parts is not None else block_gram(
         env.a_parts)
+    # c = A_k^T grad as cd_solve_all computes it; the oracle is its scan
+    atg = jnp.einsum("kdn,kd->kn", env.a_parts, grads, precision=MATMUL)
+    scan = jax.vmap(lambda g, c, x, gp, m: cd_solve_gram(
+        prob, spec, g, c, x, gp, m, part.block))
     csv_row("kernels", f"cd_glm_gram({pallas})", f"K={kk},pass=1",
-            f"{_time(lambda: cd_solve_pallas(prob, spec, env.a_parts, xp, grads, env.gp_parts, env.masks, part.block, cd_mode='gram', gram_parts=gram)):.0f}")
+            f"{_time(lambda: cd_glm.cd_solve_blocks_gram(prob, spec, gram, atg, xp, env.gp_parts, env.masks, part.block, interpret=interpret_mode())):.0f}")
     csv_row("kernels", f"cd_glm_gram({oracle})", f"K={kk},pass=1",
-            f"{_time(lambda: cd_solve_all(prob, spec, env.a_parts, xp, grads, env.gp_parts, env.masks, part.block, gram_parts=gram)):.0f}")
+            f"{_time(lambda: scan(gram, atg, xp, env.gp_parts, env.masks)):.0f}")
 
 
 if __name__ == "__main__":

@@ -23,6 +23,7 @@ neighbors the way a real exchange would.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -76,13 +77,21 @@ def block_spectral_norms(a_parts: jax.Array, iters: int = 50,
     ``(K,)`` result — the sigma_k of a run are round-invariant, so recorders
     compute them ONCE at init and record rounds never re-run the iteration.
     """
-    k, d, n_k = a_parts.shape
+    k = a_parts.shape[0]
     if cache is not None:
         cache = jnp.asarray(cache)
         if cache.shape != (k,):
             raise ValueError(f"sigma_k cache has shape {cache.shape}, "
                              f"want ({k},)")
         return cache
+    return _power_iteration(a_parts, iters, seed)
+
+
+# jitted whole, so that a recorder built in every solve compiles it once per
+# shape: eager, its fori_loop over a fresh closure compiled on every call
+@functools.partial(jax.jit, static_argnames=("iters", "seed"))
+def _power_iteration(a_parts: jax.Array, iters: int, seed: int) -> jax.Array:
+    k, d, n_k = a_parts.shape
     key = jax.random.PRNGKey(seed)
     v0 = jax.random.normal(key, (k, n_k), dtype=a_parts.dtype)
 

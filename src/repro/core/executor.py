@@ -665,29 +665,29 @@ def run_round_blocks(step_fn: Callable[[Any, Any, Any], tuple[Any, Any]],
                          else "block-dispatch")
             n_dispatch += 1
             with obs_trace.span(span_name):
-                sched_b = jax.tree.map(lambda x: jnp.asarray(x[start:stop]),
-                                       schedule)
+                # numpy slices go to the block program as they are: its C++
+                # dispatch copies them to the device without jnp.asarray's
+                # ~150 Python calls an array, which a traced run records
+                sched_b = jax.tree.map(lambda x: x[start:stop], schedule)
                 if has_cadence:
-                    t_b = jnp.arange(start, stop, dtype=jnp.int32)
-                    force_b = jnp.asarray(
-                        np.arange(start, stop) == t_total - 1)
+                    t_b = np.arange(start, stop, dtype=np.int32)
+                    force_b = np.arange(start, stop) == t_total - 1
                     carry, (aux_b, rows_b, valid_b) = run_block(
                         carry, ctx_arg, sched_b, t_b, force_b)
                     state, stop_flag = carry[0], carry[1]
                     valids.append(valid_b)
                 elif has_stop:
                     args = ((state, stop_flag), ctx_arg, sched_b,
-                            jnp.asarray(rec_all[start:stop]))
+                            rec_all[start:stop])
                     if stream is not None:
-                        args += (jnp.arange(start, stop, dtype=jnp.int32),)
+                        args += (np.arange(start, stop, dtype=np.int32),)
                     (state, stop_flag), (aux_b, rows_b, valid_b) = \
                         run_block(*args)
                     valids.append(valid_b)
                 else:
-                    args = (state, ctx_arg, sched_b,
-                            jnp.asarray(rec_all[start:stop]))
+                    args = (state, ctx_arg, sched_b, rec_all[start:stop])
                     if stream is not None:
-                        args += (jnp.arange(start, stop, dtype=jnp.int32),)
+                        args += (np.arange(start, stop, dtype=np.int32),)
                     state, (aux_b, rows_b) = run_block(*args)
                 if rows_b is not None:
                     rows.append(rows_b)

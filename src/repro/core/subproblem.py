@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.precision import MATMUL
+from repro.kernels import cd_glm
 
 # VMEM we allow the cached (n_k, n_k) Gram block to occupy per node. A TPU
 # core has ~16 MB of VMEM which the block shares with dx/h/x/scalars; half
@@ -58,6 +59,15 @@ def gram_pays(d: int, n_k: int, itemsize: int = 4,
     and the (n_k, n_k) block actually fits on chip.
     """
     return n_k < d and n_k * n_k * itemsize <= vmem_budget
+
+
+def gram_kernel_fits(k: int, n_k: int, itemsize: int = 4,
+                     vmem_budget: int = GRAM_VMEM_BUDGET) -> bool:
+    """Whether the Gram kernel over all K nodes fits the same budget: its
+    (n_k, K_pad, n_pad) column stack and six (K_pad, n_pad) vectors
+    (``cd_glm.gram_tile``), each buffered twice."""
+    k_pad, n_pad = cd_glm.gram_tile(k, n_k)
+    return 2 * (n_k + 6) * k_pad * n_pad * itemsize <= vmem_budget
 
 
 def block_gram(a_parts: jax.Array) -> jax.Array:
@@ -183,23 +193,18 @@ def cd_solve_all(problem, spec: SubproblemSpec, a_parts: jax.Array,
                  masks: jax.Array, num_steps: int,
                  step_budgets: jax.Array | None = None,
                  gram_parts: jax.Array | None = None) -> jax.Array:
-    """vmap of cd_solve over the node axis (single-host simulator path).
+    """The K nodes' local solves: cd_solve vmapped over the node axis.
 
     ``step_budgets``: optional (K,) per-node budgets (heterogeneous Theta_k).
     ``gram_parts``: optional (K, n_k, n_k) Gram blocks — when given, the
     O(n_k)-per-step Gram-cached formulation replaces the O(d) residual one
-    (numerically equivalent up to float reassociation, see module docstring).
+    (numerically equivalent up to float reassociation, see module docstring);
+    lowered for a TPU, it runs as one Pallas kernel over all K nodes.
     """
     if gram_parts is not None:
         atg = jnp.einsum("kdn,kd->kn", a_parts, grads, precision=MATMUL)
-        if step_budgets is None:
-            fn = lambda g_k, c_k, x_k, gp_k, m_k: cd_solve_gram(
-                problem, spec, g_k, c_k, x_k, gp_k, m_k, num_steps)
-            return jax.vmap(fn)(gram_parts, atg, x_parts, gp_parts, masks)
-        fn = lambda g_k, c_k, x_k, gp_k, m_k, b_k: cd_solve_gram(
-            problem, spec, g_k, c_k, x_k, gp_k, m_k, num_steps, b_k)
-        return jax.vmap(fn)(gram_parts, atg, x_parts, gp_parts, masks,
-                            step_budgets)
+        return _cd_solve_all_gram(problem, spec, gram_parts, atg, x_parts,
+                                  gp_parts, masks, num_steps, step_budgets)
     if step_budgets is None:
         fn = lambda a_k, x_k, g_k, gp_k, m_k: cd_solve(
             problem, spec, a_k, x_k, g_k, gp_k, m_k, num_steps)
@@ -208,3 +213,29 @@ def cd_solve_all(problem, spec: SubproblemSpec, a_parts: jax.Array,
         problem, spec, a_k, x_k, g_k, gp_k, m_k, num_steps, b_k)
     return jax.vmap(fn)(a_parts, x_parts, grads, gp_parts, masks,
                         step_budgets)
+
+
+def _cd_solve_all_gram(problem, spec: SubproblemSpec, gram_parts: jax.Array,
+                       atg: jax.Array, x_parts: jax.Array,
+                       gp_parts: jax.Array, masks: jax.Array, num_steps: int,
+                       step_budgets: jax.Array | None) -> jax.Array:
+    """The Gram path over all K nodes. A program lowered for a TPU runs the
+    K solves as one Pallas kernel (``kernels.cd_glm.cd_solve_blocks_gram``)
+    when its working set fits VMEM; any other program, a CPU one included,
+    runs ``cd_solve_gram`` vmapped, a ``lax.scan`` of kappa * n_k steps."""
+    def scan(gram_parts, atg, x_parts, gp_parts, masks, step_budgets):
+        # vmap maps no axis of a None budget: cd_solve_gram gets None
+        fn = lambda g_k, c_k, x_k, gp_k, m_k, b_k: cd_solve_gram(
+            problem, spec, g_k, c_k, x_k, gp_k, m_k, num_steps, b_k)
+        return jax.vmap(fn)(gram_parts, atg, x_parts, gp_parts, masks,
+                            step_budgets)
+
+    def kernel(gram_parts, atg, x_parts, gp_parts, masks, step_budgets):
+        return cd_glm.cd_solve_blocks_gram(
+            problem, spec, gram_parts, atg, x_parts, gp_parts, masks,
+            num_steps, step_budgets)
+
+    args = (gram_parts, atg, x_parts, gp_parts, masks, step_budgets)
+    if not gram_kernel_fits(*x_parts.shape, x_parts.dtype.itemsize):
+        return scan(*args)
+    return lax.platform_dependent(*args, tpu=kernel, default=scan)

@@ -1,54 +1,40 @@
 """Pallas kernels for the CoLA local subproblem solver (paper Eq. 1-2).
 
-They run in interpret mode only. The TPU compiler refuses both for a v5e,
-for two reasons:
+Both keep a solve's whole working set in VMEM for all ``kappa * n_k``
+coordinate updates, so a round's sequential recurrence costs no HBM
+traffic inside its loop (the paper's "computation between communication
+rounds", Fig. 1b, mapped onto the TPU memory hierarchy). See
+``repro.core.subproblem`` for the two formulations and their cost model.
 
-  * the ``(1, n_k)`` / ``(1, d)`` block specs on ``(K, n_k)`` / ``(K, d)``
-    arrays break the (8, 128) tiling rule: the last two dimensions of a
-    block must divide by 8 and 128 or equal the array's;
-  * with ``(K, 1, n)`` arrays and ``(1, 1, n)`` blocks instead, Mosaic
-    has no lowering for the ``dynamic_slice`` that
-    ``lax.dynamic_slice_in_dim`` makes of a column of a loaded value.
+* ``cd_solve_blocks_gram`` — the Gram-cached solve of all K nodes in one
+  call. Every node visits its coordinates in the same cyclic order, so the
+  nodes run in lockstep: node k on sublane k, coordinate i on lane i of a
+  ``gram_tile`` tile. Step i reads column i of every node's Gram block as
+  one tile from the leading axis of the (n_k, K_pad, n_pad) column stack,
+  reads coordinate i of each vector by a one-hot lane sum, and keeps
+  ``h = G dx`` with one tile axpy. The prox is the problem's own
+  ``prox_g_el``, so the arithmetic is ``cd_solve_gram``'s op for op. It
+  compiles for a v5e: ``repro.core.subproblem.cd_solve_all`` runs it in
+  every program lowered for a TPU whose working set fits
+  (``subproblem.gram_kernel_fits``), ``run_cola`` and ``run_dist_cola``
+  among them.
+* ``cd_solve_blocks`` — the residual formulation, one grid program per node
+  (grid = (K,)): VMEM holds the node's (d, n_k) column block, the residual
+  r = A_[k] dx and dx; each step does an O(d) column dot and an O(d)
+  rank-1 residual update. It runs interpreted only, and nothing on the
+  solver's path calls it. The TPU compiler refuses it for a v5e for two
+  reasons: the ``(1, n_k)`` / ``(1, d)`` block specs on ``(K, n_k)`` /
+  ``(K, d)`` arrays break the (8, 128) tiling rule, and with
+  ``(K, 1, n)`` arrays and ``(1, 1, n)`` blocks instead Mosaic has no
+  lowering for the ``dynamic_slice`` of a column of a loaded value. Its
+  prox is the generalized elastic-net family
 
-Compiling them means rewriting them. ``run_cola`` uses the pure-jnp
-``repro.core.subproblem.cd_solve_all`` instead.
+      prox(z) = clip( soft(z - step*lin_i, step*l1) / (1 + step*l2), +-box )
 
-The design they were written to: the paper's wall-clock is dominated by the
-Theta-approximate local solve (Fig. 1b communication/computation
-trade-off), so the whole node-local working set stays in VMEM for all
-``kappa * n_k`` coordinate updates:
+  which covers every ``repro.core.problems`` instance; ``ops.py`` maps a
+  Problem to its (l1, l2, box) scalars and per-coordinate ``lin`` vector.
 
-  * the node's column block A_[k]  (d x n_k tile),
-  * the residual  r = A_[k] dx     (d,),
-  * the iterate block dx           (n_k,),
-
-so a full CD pass costs exactly one HBM read of A_[k] (at tile load) and no
-HBM traffic inside the loop — the adaptation of the paper's "computation
-between communication rounds" model to the TPU memory hierarchy (DESIGN.md
-§3.3). Each grid program owns one node k (grid = (K,)); the sequential
-coordinate recurrence runs as a ``fori_loop`` whose carries (dx, r) the
-compiler keeps in VMEM/VREGs.
-
-The separable prox is the generalized elastic-net family
-
-    prox(z) = clip( soft(z - step*lin_i, step*l1) / (1 + step*l2), +-box )
-
-which covers every ``repro.core.problems`` instance (l2 / l1+box / elastic
-net / ridge-dual-with-linear-term); ``ops.py`` maps a Problem to its
-(l1, l2, box) scalars + per-coordinate ``lin`` vector, and ``ref.py`` is the
-pure-jnp oracle (``cd_solve_all``).
-
-Two kernel variants share the prox (see ``repro.core.subproblem`` for the
-cost model):
-
-* ``_cd_kernel`` — residual formulation: VMEM holds the (d, n_k) column
-  block; each coordinate step does an O(d) column dot + O(d) rank-1
-  residual update.
-* ``_cd_kernel_gram`` — Gram-cached: VMEM holds the (n_k, n_k) Gram block
-  ``A_[k]^T A_[k]`` and the precomputed ``c = A_[k]^T grad``; each step
-  maintains ``h = G dx`` with one O(n_k) column axpy. Preferred by
-  ``repro.core.subproblem.gram_pays`` when n_k < d and the Gram block fits
-  the VMEM budget; otherwise the residual kernel runs.
+``ref.py`` names the pure-jnp oracles (``cd_solve_all``).
 """
 from __future__ import annotations
 
@@ -58,6 +44,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+
+# a float32 vreg is (8, 128): the last two dimensions of a VMEM block tile
+# by these
+_SUBLANES, _LANES = 8, 128
 
 
 def _cd_kernel(a_ref, x_ref, grad_ref, lin_ref, mask_ref, dx_ref, *,
@@ -93,76 +83,100 @@ def _cd_kernel(a_ref, x_ref, grad_ref, lin_ref, mask_ref, dx_ref, *,
     dx_ref[0] = dx
 
 
-def _cd_kernel_gram(gram_ref, x_ref, atg_ref, lin_ref, mask_ref, dx_ref, *,
-                    num_steps: int, sigma_over_tau: float, l1: float,
-                    l2: float, box: float):
-    gram = gram_ref[0]    # (n_k, n_k) — the node's Gram block, in VMEM
-    x = x_ref[0]          # (n_k,)
-    atg = atg_ref[0]      # (n_k,) A_[k]^T grad_f(v_k), precomputed per round
-    lin = lin_ref[0]      # (n_k,) linear term of g_i (ridge-dual labels)
-    mask = mask_ref[0]    # (n_k,) 1 = real coordinate, 0 = padding
+def gram_tile(k: int, n_k: int) -> tuple:
+    """(K_pad, n_pad): the Gram kernel's per-node tile, K nodes on sublanes
+    and n_k coordinates on lanes, padded to the (8, 128) f32 tiling."""
+    return -(-k // _SUBLANES) * _SUBLANES, -(-n_k // _LANES) * _LANES
 
-    n_k = gram.shape[0]
-    # diag(G) = ||A_i||^2, via an iota mask (TPU-safe diagonal extraction)
-    rows = lax.broadcasted_iota(jnp.int32, (n_k, n_k), 0)
-    cols = lax.broadcasted_iota(jnp.int32, (n_k, n_k), 1)
-    col_sq = jnp.sum(jnp.where(rows == cols, gram, 0.0), axis=0)
-    q = sigma_over_tau * col_sq
+
+def _cd_kernel_gram(cols_ref, x_ref, atg_ref, gp_ref, diag_ref, mask_ref,
+                    budget_ref, dx_ref, *, num_steps: int, n_k: int,
+                    sigma_over_tau: float, prox):
+    x = x_ref[...]          # (K_pad, n_pad): node k's coordinates on lanes
+    atg = atg_ref[...]      # A_[k]^T grad_f(v_k), precomputed per round
+    gp = gp_ref[...]        # per-coordinate g parameters
+    budget = budget_ref[...]  # (K_pad, 1) step budgets (Definition 5)
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    q = sigma_over_tau * diag_ref[...]                # diag(G) = ||A_i||^2
     q_safe = jnp.where(q > 0, q, 1.0)
+    steps = 1.0 / q_safe
+    live = ((q > 0) & (mask_ref[...] > 0)).astype(x.dtype)
 
-    def coord_step(step_i, carry):
+    def coord_step(t, carry):
         dx, h = carry                                 # h = G dx
-        i = step_i % n_k                              # cyclic pass order
-        g_col = lax.dynamic_slice_in_dim(gram, i, 1, axis=1)[:, 0]
-        z = x[i] + dx[i]
-        grad_i = atg[i] + sigma_over_tau * h[i]
-        step = 1.0 / q_safe[i]
-        u = z - grad_i * step - step * lin[i]
-        soft = jnp.sign(u) * jnp.maximum(jnp.abs(u) - step * l1, 0.0)
-        z_new = jnp.clip(soft / (1.0 + step * l2), -box, box)
-        delta = jnp.where((q[i] > 0) & (mask[i] > 0), z_new - z, 0.0)
-        return dx.at[i].add(delta), h + g_col * delta
+        i = t % n_k                                   # cyclic pass order
+        sel = lane == i
+        # coordinate i of every node, (K_pad, 1): a one-hot lane sum adds
+        # only zeros, so it is exact
+        pick = lambda v: jnp.sum(jnp.where(sel, v, 0.0), axis=1,
+                                 keepdims=True)
+        dx_i = pick(dx)
+        z = pick(x) + dx_i
+        grad_i = pick(atg) + sigma_over_tau * pick(h)
+        step = pick(steps)
+        z_new = prox(z - grad_i * step, step, pick(gp))
+        ok = (pick(live) > 0) & (t < budget)
+        delta = jnp.where(ok, z_new - z, 0.0)
+        # cols_ref[i] holds column i of every node's Gram block
+        return (jnp.where(sel, dx_i + delta, dx), h + cols_ref[i] * delta)
 
-    dx0 = jnp.zeros_like(x)
-    h0 = jnp.zeros_like(x)
-    dx, _ = lax.fori_loop(0, num_steps, coord_step, (dx0, h0))
-    dx_ref[0] = dx
+    zeros = jnp.zeros_like(x)
+    dx, _ = lax.fori_loop(0, num_steps, coord_step, (zeros, zeros))
+    dx_ref[...] = dx
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "num_steps", "sigma_over_tau", "l1", "l2", "box", "interpret"))
-def cd_solve_blocks_gram(gram_parts: jax.Array, x_parts: jax.Array,
-                         atg_parts: jax.Array, lin_parts: jax.Array,
-                         masks: jax.Array, *, num_steps: int,
-                         sigma_over_tau: float, l1: float, l2: float,
-                         box: float, interpret: bool = True) -> jax.Array:
-    """Gram-cached variant of ``cd_solve_blocks``; one grid program per node.
+def cd_solve_blocks_gram(problem, spec, gram_parts: jax.Array,
+                         atg_parts: jax.Array, x_parts: jax.Array,
+                         gp_parts: jax.Array, masks: jax.Array,
+                         num_steps: int,
+                         step_budgets: jax.Array | None = None, *,
+                         interpret: bool = False) -> jax.Array:
+    """All K nodes' Gram-cached CD solves as one kernel call; the same
+    recurrence, op for op, as ``repro.core.subproblem.cd_solve_gram``
+    vmapped over the nodes.
+
+    Every node visits its coordinates in the same cyclic order, so the K
+    nodes run in lockstep: node k sits on sublane k and coordinate i on
+    lane i of a ``gram_tile(K, n_k)`` tile, and step i reads column i of
+    every node's Gram block as one tile from the leading axis of the
+    (n_k, K_pad, n_pad) column stack. Padded rows and lanes have mask 0
+    and never update.
 
     Args:
+      problem: the GLM Problem (its elementwise ``prox_g_el``).
+      spec: sigma'/tau and 1/K constants.
       gram_parts: (K, n_k, n_k) node-local Gram blocks A_[k]^T A_[k].
       atg_parts: (K, n_k) per-node A_[k]^T grad_f(v_k).
-      x_parts/lin_parts/masks: (K, n_k).
+      x_parts/gp_parts/masks: (K, n_k).
+      num_steps: coordinate updates per node (kappa * n_k).
+      step_budgets: optional (K,) per-node budgets <= num_steps.
 
     Returns dx_parts: (K, n_k).
     """
-    k, n_k, _ = gram_parts.shape
+    k, n_k = x_parts.shape
+    k_pad, n_pad = gram_tile(k, n_k)
+    pad = lambda v: jnp.pad(v, ((0, k_pad - k), (0, n_pad - n_k)))
+    cols = jnp.pad(jnp.transpose(gram_parts, (2, 0, 1)),
+                   ((0, 0), (0, k_pad - k), (0, n_pad - n_k)))
+    diag = jnp.diagonal(gram_parts, axis1=1, axis2=2)
+    if step_budgets is None:
+        step_budgets = jnp.full((k,), num_steps, jnp.int32)
+    budgets = jnp.pad(step_budgets.astype(jnp.int32), (0, k_pad - k))
+    args = (cols, pad(x_parts), pad(atg_parts), pad(gp_parts), pad(diag),
+            pad(masks), budgets[:, None])
     kernel = functools.partial(
-        _cd_kernel_gram, num_steps=num_steps, sigma_over_tau=sigma_over_tau,
-        l1=l1, l2=l2, box=box)
-    return pl.pallas_call(
-        kernel,
-        grid=(k,),
-        in_specs=[
-            pl.BlockSpec((1, n_k, n_k), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, n_k), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_k), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_k), lambda i: (i, 0)),
-            pl.BlockSpec((1, n_k), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, n_k), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((k, n_k), x_parts.dtype),
-        interpret=interpret,
-    )(gram_parts, x_parts, atg_parts, lin_parts, masks)
+        _cd_kernel_gram, num_steps=num_steps, n_k=n_k,
+        sigma_over_tau=spec.sigma_over_tau, prox=problem.prox_g_el)
+    # under shard_map the result varies over the mesh axes its inputs do
+    vma = frozenset().union(*(jax.typeof(a).vma for a in args))
+    with jax.named_scope("cola.cd_kernel"):
+        dx = pl.pallas_call(
+            kernel,
+            out_shape=jax.ShapeDtypeStruct((k_pad, n_pad), x_parts.dtype,
+                                           vma=vma),
+            interpret=interpret,
+        )(*args)
+    return dx[:k, :n_k]
 
 
 @functools.partial(jax.jit, static_argnames=(

@@ -101,6 +101,28 @@ def test_block_spectral_norms_cache_short_circuits(setup):
         block_spectral_norms(env.a_parts, cache=sigma[:-1])
 
 
+def test_a_repeated_certified_solve_compiles_nothing(setup):
+    """The recorder is built anew in every solve; its sigma_k power
+    iteration compiles once per shape, not once per solve."""
+    prob, graph, _, _, _ = setup
+    compiles = []
+
+    def on_event(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    solve = lambda: run_cola(prob, graph, ColaConfig(kappa=4.0), 40,
+                             record_every=10, recorder="certificate",
+                             eps=1e-3)
+    solve()
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        solve()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    assert not compiles
+
+
 def test_masked_neighborhood_mean_matches_neighbor_average(setup):
     """The masked formulation averages exactly the values a gossip exchange
     delivers: own gradient + each adjacency neighbor's."""

@@ -9,9 +9,11 @@ from repro.core import baselines as bl, problems, topology as topo
 from repro.core.cola import ColaConfig, build_env, run_cola
 from repro.core.executor import record_flags, run_round_blocks
 from repro.core.partition import make_partition
+from repro.core.precision import MATMUL
 from repro.core.subproblem import (SubproblemSpec, block_gram, cd_solve_all,
                                    gram_pays)
 from repro.data import synthetic
+from repro.kernels import cd_glm
 from repro.kernels.ops import cd_solve_pallas
 
 K = 8
@@ -252,9 +254,10 @@ def test_pallas_gram_kernel_matches_oracles(name):
     steps = 2 * part.block
     pl_res = cd_solve_pallas(prob, spec, env.a_parts, x_parts, grads,
                              env.gp_parts, env.masks, steps)
-    pl_grm = cd_solve_pallas(prob, spec, env.a_parts, x_parts, grads,
-                             env.gp_parts, env.masks, steps, cd_mode="gram",
-                             gram_parts=env.gram_parts)
+    atg = jnp.einsum("kdn,kd->kn", env.a_parts, grads, precision=MATMUL)
+    pl_grm = cd_glm.cd_solve_blocks_gram(prob, spec, env.gram_parts, atg,
+                                         x_parts, env.gp_parts, env.masks,
+                                         steps, interpret=True)
     oracle_grm = cd_solve_all(prob, spec, env.a_parts, x_parts, grads,
                               env.gp_parts, env.masks, steps,
                               gram_parts=env.gram_parts)

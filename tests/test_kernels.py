@@ -10,6 +10,7 @@ from repro.core.cola import build_env
 from repro.core.partition import make_partition
 from repro.core.subproblem import SubproblemSpec, cd_solve_all
 from repro.data import synthetic
+from repro.kernels import cd_glm
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ops import cd_solve_pallas
 from repro.models.attention import chunked_attention, reference_attention
@@ -174,3 +175,56 @@ def test_cd_kernel_decreases_subproblem_objective():
         g1 = eval_subproblem(prob, spec, env.a_parts[i], x_parts[i], dx[i],
                              vs[i], grads[i], env.gp_parts[i], env.masks[i])
         assert float(g1) <= float(g0) + 1e-6
+
+
+# ---------------------------------------------------------------------------
+# Gram-path CD kernel: all K nodes in one call, against cd_solve_all's scan
+# ---------------------------------------------------------------------------
+
+def _gram_problem(name, n, d=32):
+    """``name`` with n partitioned columns (samples for the ridge dual)."""
+    key = jax.random.PRNGKey(n)
+    y_len = n if name == "ridge_dual" else d
+    x = jax.random.normal(key, (y_len, d) if name == "ridge_dual"
+                          else (d, n))
+    y = jax.random.normal(jax.random.fold_in(key, 1), (y_len,))
+    if name.startswith("logistic"):
+        y = jnp.sign(y) + (jnp.sign(y) == 0)
+    return problems.PROBLEMS[name](x, y, 1e-2)
+
+
+@pytest.mark.parametrize("steps", ["partial", "three_passes", "budgets"])
+@pytest.mark.parametrize("n_k", [63, 125, 130])
+@pytest.mark.parametrize("k", [4, 6, 16])
+@pytest.mark.parametrize("name", sorted(problems.PROBLEMS))
+def test_gram_kernel_matches_gram_scan(name, k, n_k, steps):
+    """K below, above and not a multiple of 8 sublanes; n_k within one
+    128-lane tile and past it; the last node's last coordinate is padding."""
+    prob = _gram_problem(name, k * n_k - 1)
+    part = make_partition(prob.n, k)
+    assert part.block == n_k
+    env = build_env(prob, part, with_gram=True)
+    key = jax.random.PRNGKey(k + n_k)
+    x_parts = 0.1 * jax.random.normal(key, (k, n_k))
+    grads = jax.vmap(prob.grad_f)(
+        0.3 * jax.random.normal(jax.random.fold_in(key, 1), (k, prob.d)))
+    spec = SubproblemSpec(sigma_over_tau=k / prob.tau, inv_k=1.0 / k)
+    num_steps = n_k // 2 + 1 if steps == "partial" else 3 * n_k
+    budgets = None
+    if steps == "budgets":
+        budgets = jnp.asarray([(0, 1, n_k // 2, num_steps, 2 * n_k + 5)[i % 5]
+                               for i in range(k)], jnp.int32)
+    ref = cd_solve_all(prob, spec, env.a_parts, x_parts, grads, env.gp_parts,
+                       env.masks, num_steps, step_budgets=budgets,
+                       gram_parts=env.gram_parts)
+    atg = jnp.einsum("kdn,kd->kn", env.a_parts, grads,
+                     precision=jax.lax.Precision.HIGHEST)
+    out = cd_glm.cd_solve_blocks_gram(prob, spec, env.gram_parts, atg,
+                                      x_parts, env.gp_parts, env.masks,
+                                      num_steps, budgets, interpret=True)
+    ref, out = np.asarray(ref), np.asarray(out)
+    assert np.abs(ref).max() > 0
+    # the same arithmetic on the same values: bitwise on the CPU
+    np.testing.assert_array_equal(out, ref)
+    if budgets is not None:
+        assert not out[0].any()

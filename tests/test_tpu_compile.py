@@ -2,7 +2,9 @@
 
 jaxlib ships the TPU compiler, so these compile ``chip_smoke.py``'s
 phase (a) at full size — L2 logistic regression on 400,000 x 2,000, K=16 —
-for one chip and for a four-chip 2x2 host. They catch what only the TPU
+for one chip and for a four-chip 2x2 host, and the Fig.-1 lasso's round
+block (10,000 x 1,000, K=16, kappa 8) for one chip. Each must run its
+local CD solve as the Gram kernel of ``repro.kernels.cd_glm``. They catch what only the TPU
 compiler refuses: a program over the chip's memory, a layout it cannot
 tile, a collective the comm plan did not promise. A compile that passes is
 not a chip run.
@@ -12,11 +14,13 @@ one process may load the TPU library, and every test worker imports this
 file.
 """
 import os
+import re
 
 import numpy as np
 import pytest
 
 EPS_SHAPE = (400_000, 2_000)   # chip_smoke.py phase (a): samples x features
+LASSO_SHAPE = (10_000, 1_000)  # the paper's Fig. 1
 K, CHIPS, BLOCK = 16, 4, 10
 HBM_BYTES = 16 * 2 ** 30       # one v5e chip
 
@@ -56,6 +60,24 @@ def epsilon_problem():
                                 jnp.ones((d,), jnp.float32), 1.0)
 
 
+def _solve_ops(hlo: str) -> tuple:
+    """(Mosaic kernel calls, while loops) of the compiled HLO whose op_name
+    lies in the round's local solve."""
+    ops = [line for line in hlo.splitlines()
+           if re.search(r'op_name="[^"]*cola\.local_solve', line)]
+    kernels = [op for op in ops if 'custom_call_target="tpu_custom_call"' in op]
+    loops = [op for op in ops if re.search(r"= \S.* while\(", op)]
+    return kernels, loops
+
+
+def _assert_cd_kernel(hlo: str):
+    kernels, loops = _solve_ops(hlo)
+    assert len(kernels) == 1, kernels
+    assert "cola.local_solve/cond/branch_0_fun/cola.cd_kernel/" in kernels[0]
+    # the kappa * n_k coordinate steps run inside the kernel, not as a scan
+    assert not loops, loops
+
+
 def test_epsilon_round_block_fits_one_chip(topo, epsilon_problem):
     from repro.analysis import drivers
     from repro.core import topology as graphs
@@ -73,6 +95,55 @@ def test_epsilon_round_block_fits_one_chip(topo, epsilon_problem):
     assert total < HBM_BYTES, mem
     # the carried state is donated: x_parts and v_stack alias their outputs
     assert mem.alias_size_in_bytes >= K * d * 4
+    _assert_cd_kernel(compiled.as_text())
+
+
+def test_lasso_round_block_runs_the_cd_kernel(topo):
+    import jax
+    import jax.numpy as jnp
+    from repro.analysis import drivers
+    from repro.core import problems, topology as graphs
+    from repro.core.cola import ColaConfig
+
+    d, n = LASSO_SHAPE
+    prob = problems.lasso(jax.ShapeDtypeStruct((d, n), jnp.float32),
+                          jnp.ones((d,), jnp.float32), 0.05, box=5.0)
+    compiled = drivers.sim_block_compiled(
+        prob, graphs.ring(K), ColaConfig(kappa=8.0, cd_mode="gram"), BLOCK,
+        device=topo.devices[0])
+    _assert_cd_kernel(compiled.as_text())
+
+
+@pytest.mark.parametrize("k", [10, K])
+def test_budgeted_solve_runs_the_cd_kernel(topo, k):
+    """Per-node step budgets (Definition 5), over all K nodes and over a
+    cohort's gathered K' = 10, which is no multiple of 8 sublanes."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from repro.core import problems
+    from repro.core.subproblem import SubproblemSpec, cd_solve_all
+
+    d, n = LASSO_SHAPE
+    n_k = n // K
+    prob = problems.lasso(jax.ShapeDtypeStruct((d, k * n_k), jnp.float32),
+                          jnp.ones((d,), jnp.float32), 0.05, box=5.0)
+    spec = SubproblemSpec(sigma_over_tau=float(k), inv_k=1.0 / k)
+
+    def solve(a_parts, gram_parts, x_parts, grads, gp_parts, masks,
+              budgets):
+        with jax.named_scope("cola.local_solve"):
+            return cd_solve_all(prob, spec, a_parts, x_parts, grads,
+                                gp_parts, masks, 8 * n_k,
+                                step_budgets=budgets, gram_parts=gram_parts)
+
+    on_chip = SingleDeviceSharding(topo.devices[0])
+    arg = lambda *shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=on_chip)
+    compiled = jax.jit(solve).lower(
+        arg(k, d, n_k), arg(k, n_k, n_k), arg(k, n_k), arg(k, d),
+        arg(k, n_k), arg(k, n_k), arg(k, dtype=jnp.int32)).compile()
+    _assert_cd_kernel(compiled.as_text())
 
 
 def test_plan_round_on_four_chips(topo, epsilon_problem):
@@ -90,3 +161,5 @@ def test_plan_round_on_four_chips(topo, epsilon_problem):
     assert 0 < counts["collective-permute"] <= plan.num_colors, counts
     assert counts["all-gather"] == 0, counts
     assert np.isclose(counts["all-reduce"], 0), counts
+    # each chip solves its K / 4 nodes in the kernel
+    _assert_cd_kernel(hlo)
