@@ -3,13 +3,18 @@
 The single-host simulator (``repro.core.cola.run_cola``) keeps all K nodes
 stacked in one device's arrays; this driver lays the node axis over a mesh
 axis instead, so K paper-nodes execute as K/M node blocks on M devices with
-no coordinator. Three design rules make it bit-compatible with the simulator
-and as cheap to dispatch:
+no coordinator. These design rules make it bit-compatible with the
+simulator and as cheap to dispatch:
 
 * **same round body** — the per-round function is ``cola._round_body`` with
   only the two mixing hooks swapped for collective implementations, so every
   node-local op (CD solve, local updates, churn masking) is literally the
   simulator's code;
+* **each device builds its own blocks** — A is laid out by columns over
+  the node axis and one program relayouts each device's K/M node blocks
+  and their Gram products where they live (``build_env_on_mesh``): no
+  device holds another device's blocks, so the node blocks need never fit
+  on one;
 * **same executor** — rounds run through the round-block scan engine
   (``repro.core.executor.run_round_blocks``): ``block_size`` rounds per
   dispatch, schedules pre-materialized by the simulator's own
@@ -54,6 +59,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+from functools import partial
 from typing import Callable
 
 import jax
@@ -65,14 +71,15 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import executor as exec_engine, metrics as metrics_lib, \
     mixing, quant, topology as topo
 from repro.topo import lowering as topo_lowering, plan as topo_plan
-from repro.core.cola import (ColaConfig, RunResult,
+from repro.core.cola import (ColaConfig, ColaEnv, RunResult,
                              _arm_wire_state, _as_schedule_fn,
                              _check_wire_config,
                              _materialize_schedule, _reset_leavers,
-                             _round_body, build_env, init_state)
+                             _round_body, init_state)
 from repro.core.duality import consensus_residual, neighborhood_mean
-from repro.core.partition import make_partition
+from repro.core.partition import Partition, make_partition
 from repro.core.problems import Problem
+from repro.core.subproblem import block_gram
 from repro.dist.sharding import (auto_mesh, block_payload_pspec,
                                  cola_counters_pspecs, cola_env_pspecs,
                                  cola_recorder_pspecs, cola_state_pspecs,
@@ -577,6 +584,108 @@ class _DistRecorder:
                 self._inner.cache_token())
 
 
+# ---------------------------------------------------------------------------
+# the env, built on the devices that hold it
+# ---------------------------------------------------------------------------
+
+_ON_MESH = "_on_mesh_memo"
+
+
+def place_columns(x: jax.Array, part: Partition, mesh, axis: str
+                  ) -> jax.Array:
+    """``x`` (..., n) laid out by columns over ``axis``: each device holds
+    the columns of its own K/M nodes, zero-padded to ``part.n_padded`` as
+    ``Partition.split_matrix`` pads them. An ``x`` already laid out so is
+    returned as it is; any other is moved one device's columns at a time,
+    never as one copy of all of them."""
+    sharding = NamedSharding(mesh, P(*(None,) * (x.ndim - 1), axis))
+    shape = x.shape[:-1] + (part.n_padded,)
+    held = getattr(x, "sharding", None)
+    if x.shape == shape and held is not None and held.is_equivalent_to(
+            sharding, x.ndim):
+        return x
+    shards = []
+    for dev, index in sharding.addressable_devices_indices_map(
+            shape).items():
+        lo, hi, _ = index[-1].indices(part.n_padded)
+        cols = jax.device_put(x[..., min(lo, part.n):min(hi, part.n)], dev)
+        pad = hi - lo - cols.shape[-1]
+        if pad:
+            cols = jnp.pad(cols, ((0, 0),) * (x.ndim - 1) + ((0, pad),))
+        shards.append(cols)
+    return jax.make_array_from_single_device_arrays(shape, sharding, shards)
+
+
+@partial(jax.jit, static_argnames=("part", "mesh", "axis", "with_gram"))
+def _env_build(a_cols, gp_cols, *, part: Partition, mesh, axis: str,
+               with_gram: bool) -> ColaEnv:
+    """Each device's ``ColaEnv`` block from its own columns of A and of the
+    g parameters (None: zeros): ``build_env``'s arrays, laid out by node."""
+    ln, b = part.num_nodes // mesh.shape[axis], part.block
+
+    def build(a_l, gp_l):
+        with jax.named_scope("cola.env_build"):
+            # each node's columns written in place into the (ln, d, n_k)
+            # blocks: one copy, with no relayout buffer in between
+            a_parts = jnp.zeros((ln,) + a_l.shape[:1] + (b,), a_l.dtype)
+            for j in range(ln):
+                a_parts = a_parts.at[j].set(a_l[:, j * b:(j + 1) * b])
+            col = lax.axis_index(axis) * ln * b + jnp.arange(ln * b)
+            masks = (col < part.n).reshape(ln, b).astype(a_l.dtype)
+            gp_parts = (jnp.zeros((ln, b), a_l.dtype) if gp_l is None
+                        else gp_l.reshape(ln, b))
+            return ColaEnv(a_parts=a_parts, gp_parts=gp_parts, masks=masks,
+                           gram_parts=block_gram(a_parts) if with_gram
+                           else None)
+
+    return jax.shard_map(build, mesh=mesh,
+                         in_specs=(P(None, axis), P(axis)),
+                         out_specs=cola_env_pspecs(axis))(a_cols, gp_cols)
+
+
+def problem_on_mesh(problem: Problem, part: Partition, mesh,
+                    axis: str) -> Problem:
+    """``problem`` with A laid out by columns over ``axis``
+    (``place_columns``, host span ``env-place``) where its columns split
+    into the nodes with no padding; else ``problem`` as it is.
+
+    The gap recorder reads A whole, and its compiled program takes A laid
+    out by columns: handed an A that lives on one device, every block
+    dispatch would move A's columns to the devices again. The placed
+    problem is kept on ``problem`` for its mesh and axis (as
+    ``executor.fingerprint`` keeps its digest), so the solves of one
+    problem move A once."""
+    if part.pad_width():
+        return problem
+    memo = getattr(problem, _ON_MESH, None)
+    if memo is not None and memo[0] == (mesh, axis):
+        return memo[1]
+    from repro.obs import trace as obs_trace   # obs imports core.cola
+    with obs_trace.span("env-place"):
+        placed = dataclasses.replace(
+            problem, a=place_columns(problem.a, part, mesh, axis))
+    object.__setattr__(problem, _ON_MESH, ((mesh, axis), placed))
+    return placed
+
+
+def build_env_on_mesh(problem: Problem, part: Partition, mesh, axis: str, *,
+                      with_gram: bool) -> ColaEnv:
+    """``build_env``'s arrays, bit for bit, laid out by node over ``axis``,
+    each device relayouting and taking the Gram products of its own K/M
+    nodes only: A is placed by columns first (host span ``env-place``; an
+    A laid out so, as ``problem_on_mesh`` leaves it, is used as it is),
+    then one program builds every device's block (device scope
+    ``cola.env_build``). No device ever holds another device's node
+    blocks."""
+    from repro.obs import trace as obs_trace   # obs imports core.cola
+    with obs_trace.span("env-place"):
+        a_cols = place_columns(problem.a, part, mesh, axis)
+        gp_cols = (None if problem.g_param is None
+                   else place_columns(problem.g_param, part, mesh, axis))
+    return _env_build(a_cols, gp_cols, part=part, mesh=mesh, axis=axis,
+                      with_gram=with_gram)
+
+
 def run_dist_cola(problem: Problem, graph: topo.Topology, cfg: ColaConfig,
                   mesh, rounds: int, *, comm: str = "ring",
                   axis: str | None = None, conn: int = 1,
@@ -703,9 +812,11 @@ def run_dist_cola(problem: Problem, graph: topo.Topology, cfg: ColaConfig,
 
     part = make_partition(problem.n, k)
     with obs_trace.span("env-build"):
-        env = build_env(problem, part,
-                        with_gram=cfg.use_gram(problem.d, part.block,
-                                               problem.a.dtype.itemsize))
+        problem = problem_on_mesh(problem, part, mesh, axis)
+        env = build_env_on_mesh(
+            problem, part, mesh, axis,
+            with_gram=cfg.use_gram(problem.d, part.block,
+                                   problem.a.dtype.itemsize))
     state = init_state(problem, part)
     dtype = problem.a.dtype
     atk_info = None
@@ -753,13 +864,11 @@ def run_dist_cola(problem: Problem, graph: topo.Topology, cfg: ColaConfig,
             # (metrics.attackify)
             rec = metrics_lib.attackify(rec)
 
-    # lay the node axis of state + env over the mesh axis up front so the
-    # donated buffers never migrate between blocks
+    # lay the node axis of the state over the mesh axis up front (the env
+    # is built there) so the donated buffers never migrate between blocks
     state_spec, env_spec = cola_state_pspecs(axis), cola_env_pspecs(axis)
     state = jax.tree.map(
         lambda x: jax.device_put(x, NamedSharding(mesh, state_spec)), state)
-    env = jax.tree.map(
-        lambda x: jax.device_put(x, NamedSharding(mesh, env_spec)), env)
     obs_upd = obs_inc = None
     if cfg.telemetry:
         # counters attach AFTER the state placement with their OWN specs

@@ -163,3 +163,93 @@ def test_plan_round_on_four_chips(topo, epsilon_problem):
     assert np.isclose(counts["all-reduce"], 0), counts
     # each chip solves its K / 4 nodes in the kernel
     _assert_cd_kernel(hlo)
+
+
+def test_env_built_on_each_of_four_chips(topo):
+    """``run_dist_cola``'s env build for the 2x2 host: from A laid out by
+    columns, each chip holds a quarter of A and writes a quarter of the
+    node blocks, with no whole-env buffer anywhere. Layouts are pinned to
+    the descending ones a chip's client gives its arrays."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core.partition import make_partition
+    from repro.dist import runtime
+    from repro.dist.sharding import auto_mesh
+    from repro.launch import hlo_analysis
+
+    mesh = auto_mesh(jax.make_mesh((CHIPS,), ("data",),
+                                   devices=topo.devices))
+    d, n = EPS_SHAPE
+    part = make_partition(n, K)
+
+    def fmt(ndim, spec):
+        return Format(Layout(major_to_minor=tuple(range(ndim))),
+                      NamedSharding(mesh, spec))
+
+    build = jax.jit(runtime._env_build.__wrapped__,
+                    static_argnames=("part", "mesh", "axis", "with_gram"),
+                    out_shardings=runtime.ColaEnv(
+                        fmt(3, P("data")), fmt(2, P("data")),
+                        fmt(2, P("data")), fmt(3, P("data"))))
+    a = jax.ShapeDtypeStruct((d, n), jnp.float32,
+                             sharding=fmt(2, P(None, "data")))
+    compiled = build.lower(a, None, part=part, mesh=mesh, axis="data",
+                           with_gram=True).compile()
+    mem = compiled.memory_analysis()
+    quarter = d * n * 4 // CHIPS
+    # A's columns in, the node blocks out (lanes padded 500 -> 512 and
+    # 125 -> 128), plus the Gram blocks and masks
+    assert quarter <= mem.argument_size_in_bytes <= 1.05 * quarter, mem
+    assert quarter <= mem.output_size_in_bytes <= 1.05 * quarter, mem
+    # at most one node's columns in flight: never a relayout of the whole
+    assert mem.temp_size_in_bytes <= quarter / 2, mem
+    hlo = compiled.as_text()
+    assert "cola.env_build" in hlo
+    # each chip builds from its own columns: nothing crosses a link
+    counts = hlo_analysis.analyze(hlo)["collective_counts"]
+    assert not any(counts.values()), counts
+
+
+def test_gap_record_takes_a_by_columns_on_four_chips(topo):
+    """The gap recorder's row on the 2x2 host: the compiler lays an A it is
+    handed on one device out by columns, and an A laid out so by
+    ``problem_on_mesh`` compiles to the same ops, so the recorder's rows
+    do not depend on where A arrives."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core import problems
+    from repro.core.duality import gap_report
+    from repro.core.partition import make_partition
+    from repro.dist.sharding import auto_mesh
+
+    mesh = auto_mesh(jax.make_mesh((CHIPS,), ("data",),
+                                   devices=topo.devices))
+    d, n = EPS_SHAPE
+    part = make_partition(n, K)
+
+    def row(a, y, x_parts, v_stack):
+        rep = gap_report(problems.logistic_l2(a, y, 1.0), part, x_parts,
+                         v_stack)
+        return jnp.stack([rep.gap, rep.primal, rep.consensus_violation])
+
+    def compiled(a_sharding):
+        node = NamedSharding(mesh, P("data"))
+        sds = jax.ShapeDtypeStruct
+        return jax.jit(row).lower(
+            sds((d, n), jnp.float32, sharding=a_sharding),
+            sds((d,), jnp.float32), sds((K, n // K), jnp.float32,
+                                        sharding=node),
+            sds((K, d), jnp.float32, sharding=node)).compile()
+
+    def ops(c):
+        return [re.sub(r", (metadata|sharding|frontend_attributes)=\{[^}]*\}",
+                       "", line) for line in c.as_text().splitlines()
+                if "%" in line and " = " in line]
+
+    by_columns = NamedSharding(mesh, P(None, "data"))
+    arrives = compiled(None)
+    assert arrives.input_shardings[0][0].is_equivalent_to(by_columns, 2)
+    assert ops(arrives) == ops(compiled(by_columns))
